@@ -124,6 +124,21 @@ print(json.dumps(out))
 """
 
 
+def cpu_child_env() -> Dict[str, str]:
+    """Environment for a benchmark child process that forces host devices.
+
+    The parent has imported JAX and may hold the accelerator; a child that
+    needs it too would fail or hang. Such children therefore run on XLA:CPU
+    explicitly, and the rows they produce say ``platform=cpu``.
+    """
+    import os
+
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def spmd_train_curves(runs: List[Dict]) -> List[Dict]:
     """Run `train_curve`-style async trainings on the SPMD backend.
 
@@ -134,8 +149,10 @@ def spmd_train_curves(runs: List[Dict]) -> List[Dict]:
     sweeps cross-validate the sim convergence claims on the real shard_map
     runtime without a process per point. ``data_par > 1`` shards the batch
     over replicas; ``data_async``/``data_delay`` route the cross-replica
-    gradient reduction through the engine's deferred FIFO. Staleness matches the sim path: the per-stage delay FIFO on the
-    stage-stacked layout == the simulator's per-leaf FIFO.
+    gradient reduction through the engine's deferred FIFO. Staleness
+    matches the sim path: the per-stage delay FIFO on the stage-stacked
+    layout == the simulator's per-leaf FIFO. The subprocess runs on XLA:CPU
+    (`cpu_child_env`); every result carries ``"platform": "cpu"``.
     """
     import json
     import os
@@ -150,16 +167,15 @@ def spmd_train_curves(runs: List[Dict]) -> List[Dict]:
         "devices": max(r["stages"] * r["data_par"] for r in runs),
         "runs": repr(runs),
     }
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        env=env, timeout=3600,
+        env=cpu_child_env(), timeout=3600,
     )
     if out.returncode != 0:
         raise RuntimeError(f"spmd curve subprocess failed: {out.stderr[-2000:]}")
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    return [{**r, "platform": "cpu"}
+            for r in json.loads(out.stdout.strip().splitlines()[-1])]
 
 
 def iters_to_loss(losses: Sequence[float], target: float) -> Optional[int]:

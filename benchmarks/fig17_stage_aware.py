@@ -4,8 +4,9 @@ backend.
 
 ``--backend sim`` (default) runs the virtual-stage simulation; ``--backend
 spmd`` runs the same three allocations on the shard_map pipeline runtime
-(subprocess with forced host devices), where the per-stage periods live
-inside one stacked ``(K, per, m, n)`` leaf via the vectorized refresh mask.
+(a subprocess on XLA:CPU with forced host devices), where the per-stage
+periods live inside one stacked ``(K, per, m, n)`` leaf via the vectorized
+refresh mask.
 
 The sim sweep is 2-D: each allocation runs at every data delay in
 ``DATA_DELAYS`` (0 = pipeline staleness only; D > 0 composes the uniform
@@ -23,7 +24,7 @@ import sys
 
 sys.path.insert(0, "src")
 
-from benchmarks.common import tail, train_curve
+from benchmarks.common import cpu_child_env, tail, train_curve
 
 ALLOCATIONS = (
     ("uniform", {}),
@@ -41,14 +42,14 @@ import jax
 from repro.configs.base import ModelConfig, AttentionConfig, BlockSpec, OptimizerConfig
 from repro.data import batches
 from repro.engine import LoopConfig, SpmdEngine, run_loop
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import auto_axes
 
 cfg = ModelConfig(num_layers=%(stages)d, d_model=32, d_ff=64, vocab_size=64,
                   max_seq_len=64,
                   attention=AttentionConfig(num_heads=2, num_kv_heads=2, head_dim=16),
                   pattern=(BlockSpec("attn", "dense"),), scan_layers=False)
 K, M, steps = %(stages)d, %(stages)d, %(steps)d
-mesh = make_mesh_compat((K, 1), ("stage", "data"))
+mesh = jax.make_mesh((K, 1), ("stage", "data"), axis_types=auto_axes(2))
 rows = []
 for label, kw in %(allocs)s:
     ocfg = OptimizerConfig(name="basis_rotation", learning_rate=3e-3,
@@ -75,12 +76,10 @@ def spmd_rows(quick: bool = True):
     script = SPMD_SCRIPT % {
         "stages": stages, "steps": steps, "allocs": repr(ALLOCATIONS),
     }
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        env=env, timeout=1800,
+        env=cpu_child_env(), timeout=1800,
     )
     if out.returncode != 0:
         raise RuntimeError(f"fig17 spmd subprocess failed: {out.stderr[-2000:]}")
@@ -89,7 +88,7 @@ def spmd_rows(quick: bool = True):
         rows.append({
             "name": f"fig17/spmd_{r['label']}",
             "us_per_call": r["us_per_step"],
-            "derived": f"K={stages};final={r['final']:.3f}",
+            "derived": f"K={stages};final={r['final']:.3f};platform=cpu",
         })
     return rows
 

@@ -4,7 +4,7 @@
 (b) basis-update frequency sweep (performance degrades only mildly);
 (c) stage-aware vs uniform vs reversed allocation under the same budget;
 (d) SPMD schedule comparison — fill-drain vs 1F1B step time on the real
-    shard_map runtime (subprocess with forced host devices)."""
+    shard_map runtime (a subprocess on XLA:CPU with forced host devices)."""
 from __future__ import annotations
 
 import json
@@ -14,7 +14,7 @@ import sys
 
 sys.path.insert(0, "src")
 
-from benchmarks.common import tail, train_curve
+from benchmarks.common import cpu_child_env, tail, train_curve
 
 SPMD_TIMING_SCRIPT = r"""
 import sys
@@ -26,14 +26,14 @@ import jax
 from repro.configs.base import ModelConfig, AttentionConfig, BlockSpec, OptimizerConfig
 from repro.data import batches
 from repro.engine import LoopConfig, SpmdEngine, run_loop
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import auto_axes
 
 cfg = ModelConfig(num_layers=%(stages)d, d_model=32, d_ff=64, vocab_size=64,
                   max_seq_len=64,
                   attention=AttentionConfig(num_heads=2, num_kv_heads=2, head_dim=16),
                   pattern=(BlockSpec("attn", "dense"),), scan_layers=False)
 K, M, steps = %(stages)d, %(microbatches)d, %(steps)d
-mesh = make_mesh_compat((K, 1), ("stage", "data"))
+mesh = jax.make_mesh((K, 1), ("stage", "data"), axis_types=auto_axes(2))
 rows = []
 for sched in %(schedules)s:
     ocfg = OptimizerConfig(name="adam", learning_rate=1e-3, total_steps=steps,
@@ -61,12 +61,10 @@ def spmd_schedule_rows(quick: bool = True, schedules=("fill_drain", "1f1b")):
         "stages": stages, "microbatches": microbatches, "steps": steps,
         "schedules": repr(tuple(schedules)),
     }
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        env=env, timeout=1800,
+        env=cpu_child_env(), timeout=1800,
     )
     if out.returncode != 0:
         raise RuntimeError(f"spmd timing subprocess failed: {out.stderr[-2000:]}")
@@ -75,7 +73,8 @@ def spmd_schedule_rows(quick: bool = True, schedules=("fill_drain", "1f1b")):
         rows.append({
             "name": f"fig9d/spmd_{r['schedule']}",
             "us_per_call": r["us_per_step"],
-            "derived": f"K={stages};M={microbatches};final={r['final']:.3f}",
+            "derived": (f"K={stages};M={microbatches};"
+                        f"final={r['final']:.3f};platform=cpu"),
         })
     return rows
 

@@ -345,7 +345,7 @@ def data_axis_rows(quick: bool):
             "derived": (
                 f"stages=2;data_par=2;steps={steps};"
                 f"delay={kw.get('data_delay', 0)};"
-                f"final={tail(r['losses'], 3):.3f}"
+                f"final={tail(r['losses'], 3):.3f};platform={r['platform']}"
             ),
         })
     return rows
@@ -374,6 +374,7 @@ def bench_payload(rows, quick: bool):
         "rows": rows,
         "trajectory": {
             "config": run,
+            "platform": res["platform"],
             "step_time_us": res["us_per_step"],
             "final_loss": tail(res["losses"], 3),
             "losses": res["losses"],

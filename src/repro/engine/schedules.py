@@ -201,9 +201,7 @@ def make_fill_drain_loss(
         loss = jax.lax.pmean(loss, data_axis)
         return loss
 
-    from jax.experimental.shard_map import shard_map
-
-    ln = shard_map(
+    ln = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(
@@ -213,7 +211,7 @@ def make_fill_drain_loss(
             P(None, data_axis, None),
         ),  # data_axis may be a tuple of mesh axes (multi-pod)
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     def loss_fn(stage_params, shared, batch):
@@ -323,9 +321,7 @@ def make_fill_drain_local_grad(
             jax.tree.map(lambda a: a[None], gsh),
         )
 
-    from jax.experimental.shard_map import shard_map
-
-    gf = shard_map(
+    gf = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(
@@ -339,7 +335,7 @@ def make_fill_drain_local_grad(
             P(data_axis, stage_axis),
             P(data_axis),
         ),
-        check_rep=False,
+        check_vma=False,
     )
 
     def grad_fn(stage_params, shared, batch):
@@ -507,13 +503,11 @@ def make_1f1b_grad(
         g_shared = jax.tree.map(lambda a: a[None], g_shared)
         return loss[None], g_stage, g_shared
 
-    from jax.experimental.shard_map import shard_map
-
     out_specs = (
         (P(), P(stage_axis), P()) if reduce_data
         else (P(data_axis), P(data_axis, stage_axis), P(data_axis))
     )
-    gf = shard_map(
+    gf = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(
@@ -523,7 +517,7 @@ def make_1f1b_grad(
             P(None, data_axis, None),
         ),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
     def grad_fn(stage_params, shared, batch):

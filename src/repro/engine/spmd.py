@@ -382,6 +382,7 @@ class SpmdEngine(PipelineEngine):
             jax.jit(self._step_fn, donate_argnums=(0, 1, 2)) if self.donate
             else jax.jit(self._step_fn)
         )
+        self._executables: Dict = {}
         self._stage_shapes = (stacked_s, shared_s)
 
     def init_state(self, params: Any = None, key: Any = None) -> EngineState:
@@ -463,15 +464,49 @@ class SpmdEngine(PipelineEngine):
             )
         return out
 
+    def _step_args(self, state: EngineState, batch: Dict, t: int) -> Tuple:
+        stacked, shared = state.params
+        batch = self._shape_batch(batch)
+        if self._data_eff:
+            return (stacked, shared, state.opt_state, state.data_fifo[0],
+                    batch, jnp.int32(t))
+        return stacked, shared, state.opt_state, batch, jnp.int32(t)
+
+    def _executable(self, args: Tuple):
+        """The compiled step for these arguments, compiled on first use.
+
+        The state `init_state` builds is uncommitted, so the compiler lays
+        it out, and the step returns it in that same layout. Reusing the
+        executable whose input shardings the arguments already have keeps
+        those committed outputs from compiling the step a second time, as a
+        plain `jax.jit` call would. Arguments laid out otherwise (a state
+        restored onto another mesh layout) get an executable of their own.
+        """
+        leaves = jax.tree.leaves(args)
+        key = (jax.tree.structure(args),
+               tuple((a.shape, a.dtype) for a in leaves))
+        known = self._executables.setdefault(key, [])
+        for exe in known:
+            want = jax.tree.leaves(exe.input_shardings[0])
+            if all(not isinstance(a, jax.Array)
+                   or a.sharding.is_equivalent_to(w, a.ndim)
+                   for a, w in zip(leaves, want)):
+                return exe
+        exe = self._jit_step.lower(*args).compile()
+        known.append(exe)
+        return exe
+
+    def executable(self, state: EngineState, batch: Dict, t: int = 0):
+        """The compiled program `step` runs on these arguments; its
+        ``as_text()`` shows which kernels the device runs."""
+        return self._executable(self._step_args(state, batch, t))
+
     def step(
         self, state: EngineState, batch: Dict, t: int
     ) -> Tuple[EngineState, Any, Dict]:
-        stacked, shared = state.params
-        batch = self._shape_batch(batch)
+        args = self._step_args(state, batch, t)
         if not self._data_eff:
-            stacked, shared, opt_state, loss = self._jit_step(
-                stacked, shared, state.opt_state, batch, jnp.int32(t)
-            )
+            stacked, shared, opt_state, loss = self._executable(args)(*args)
             return (
                 EngineState((stacked, shared), opt_state),
                 loss,
@@ -482,10 +517,9 @@ class SpmdEngine(PipelineEngine):
         # it. Both programs are async-dispatched, and since nothing needs
         # the reduce result for D more steps, the all-reduce overlaps with
         # the next steps' compute instead of serializing each one.
-        fifo = list(state.data_fifo)
-        gbar = fifo.pop(0)
-        stacked, shared, opt_state, loss_r, local = self._jit_step(
-            stacked, shared, state.opt_state, gbar, batch, jnp.int32(t)
+        fifo = list(state.data_fifo[1:])
+        stacked, shared, opt_state, loss_r, local = self._executable(args)(
+            *args
         )
         loss, reduced = self._jit_reduce(loss_r, local)
         fifo.append(reduced)

@@ -20,6 +20,13 @@ Row statistics never hit HBM unnormalised: only O and L are saved, so the
 residual cost is O(S·dh + S) per head — what the 1F1B input stash budget
 assumes (DESIGN.md §9).
 
+TPU tiling: the last two dims of every block must be multiples of (8, 128)
+or span the whole array. Per-row statistics therefore cross the kernel
+boundary lane-broadcast as (BH, Sp, 128) blocks of (1, bq, 128), the layout
+of the TPU flash attention in `jax.experimental.pallas.ops`. Only lane 0 is
+kept as the residual L (BH, Sp); the backward broadcasts L and D back to
+128 lanes just before its kernels.
+
 Masking: fully-masked tiles are guarded (p forced to 0) so they contribute
 nothing to l/acc; fully-masked *rows* produce exactly-zero output and an
 L sentinel of NEG_INF. Sequence lengths that do not divide the block sizes
@@ -41,6 +48,7 @@ from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
 _EPS = 1e-30
+LANES = 128  # lane width of a TPU vreg: row statistics are broadcast to it
 
 
 def _round_up(n: int, mult: int) -> int:
@@ -121,7 +129,7 @@ def _flash_fwd_kernel(
         lse = jnp.where(
             l > 0.0, m_ref[...] + jnp.log(jnp.maximum(l, _EPS)), NEG_INF
         )
-        L_ref[0] = lse[:, 0]
+        L_ref[0] = jnp.broadcast_to(lse, L_ref.shape[1:])
 
 
 def _flash_forward(cfg, qf, kf, vf):
@@ -130,7 +138,7 @@ def _flash_forward(cfg, qf, kf, vf):
     BH, Sp, dh = qf.shape
     scale = 1.0 / math.sqrt(dh)
     k_steps = Sp // bk
-    return pl.pallas_call(
+    o, L = pl.pallas_call(
         functools.partial(
             _flash_fwd_kernel, scale=scale, bq=bq, bk=bk, k_steps=k_steps,
             causal=causal, window=window, seq_len=seq_len,
@@ -143,15 +151,16 @@ def _flash_forward(cfg, qf, kf, vf):
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, bq, LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sp, dh), qf.dtype),
-            jax.ShapeDtypeStruct((BH, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Sp, LANES), jnp.float32),
         ],
         scratch_shapes=_scratch([(bq, 1), (bq, 1), (bq, dh)]),
         interpret=interpret,
     )(qf, kf, vf)
+    return o, L[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -175,17 +184,17 @@ def _flash_bwd_dq_kernel(
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    L = L_ref[0]  # (bq,) f32
-    D = D_ref[0]  # (bq,) f32
+    L = L_ref[0][:, :1]  # (bq, 1) f32, lane 0 of the broadcast block
+    D = D_ref[0][:, :1]
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     mask = _tile_mask(iq, ik, bq, bk, causal=causal, window=window,
                       seq_len=seq_len)
     # fully-masked rows carry L = NEG_INF; exp overflows there but the mask
     # zeroes every such entry before it can propagate
-    p = jnp.where(mask, jnp.exp(s - L[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - L), 0.0)
     dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - D[:, None])
+    ds = p * (dp - D)
     acc_ref[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32) * scale
 
     @pl.when(ik == k_steps - 1)
@@ -211,16 +220,16 @@ def _flash_bwd_dkv_kernel(
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    L = L_ref[0]
-    D = D_ref[0]
+    L = L_ref[0][:, :1]
+    D = D_ref[0][:, :1]
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     mask = _tile_mask(iq, ik, bq, bk, causal=causal, window=window,
                       seq_len=seq_len)
-    p = jnp.where(mask, jnp.exp(s - L[:, None]), 0.0)  # (bq, bk)
+    p = jnp.where(mask, jnp.exp(s - L), 0.0)  # (bq, bk)
     dv_acc[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
     dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - D[:, None])
+    ds = p * (dp - D)
     dk_acc[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32) * scale
 
     @pl.when(iq == q_steps - 1)
@@ -237,10 +246,12 @@ def _flash_backward(cfg, qf, kf, vf, o, L, do):
     q_steps, k_steps = Sp // bq, Sp // bk
     # D_i = rowsum(dO ⊙ O) — cheap elementwise reduce, plain XLA
     D = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    lanes = lambda r: jnp.broadcast_to(r[..., None], (BH, Sp, LANES))
+    L, D = lanes(L), lanes(D)
 
     q_spec = pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0))
-    row_spec = pl.BlockSpec((1, bq), lambda b, i, j: (b, i))
+    row_spec = pl.BlockSpec((1, bq, LANES), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, scale=scale, bq=bq, bk=bk, k_steps=k_steps,
@@ -257,7 +268,7 @@ def _flash_backward(cfg, qf, kf, vf, o, L, do):
     # transposed grid: K blocks outer, Q innermost, accumulate over queries
     q_spec_t = pl.BlockSpec((1, bq, dh), lambda b, j, i: (b, i, 0))
     kv_spec_t = pl.BlockSpec((1, bk, dh), lambda b, j, i: (b, j, 0))
-    row_spec_t = pl.BlockSpec((1, bq), lambda b, j, i: (b, i))
+    row_spec_t = pl.BlockSpec((1, bq, LANES), lambda b, j, i: (b, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, scale=scale, bq=bq, bk=bk, q_steps=q_steps,

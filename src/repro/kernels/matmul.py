@@ -18,13 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific memory spaces; absent on CPU-only installs is fine
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
@@ -80,15 +74,7 @@ def matmul(
     Np = b_p.shape[1]
     k_steps = Kp // bk
 
-    scratch = (
-        [pltpu.VMEM((bm, bn), jnp.float32)]
-        if (pltpu is not None and not interpret)
-        else [pl.BlockSpec(memory_space=None)]
-    )
-    # In interpret mode scratch_shapes still needs concrete ShapeDtypeStructs.
-    scratch = [jax.ShapeDtypeStruct((bm, bn), jnp.float32)]
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
+    scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
 
     out = pl.pallas_call(
         functools.partial(_matmul_kernel, k_steps=k_steps),

@@ -27,7 +27,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.configs import ARCH_IDS, INPUT_SHAPES, InputShape, ModelConfig, OptimizerConfig, get_config  # noqa: E402
 from repro.configs.catalog import shapes_for  # noqa: E402
-from repro.launch.mesh import make_production_mesh, mesh_shape_dict, use_mesh  # noqa: E402
+from repro.launch.mesh import make_production_mesh, mesh_shape_dict  # noqa: E402
 from repro.launch.roofline import Roofline, roofline_from_compiled  # noqa: E402
 from repro.models.model import forward_decode, forward_train, init_cache, init_model, loss_fn  # noqa: E402
 from repro.optim.base import apply_updates, clip_by_global_norm  # noqa: E402
@@ -214,7 +214,7 @@ def _compile(cfg: ModelConfig, shape: InputShape, mesh, optimizer, rotation, arc
     )
     batch = input_specs(cfg, shape, mesh)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.mode == "train":
             src, geom = rotation or rotation_strategy(arch)
             ocfg = OptimizerConfig(

@@ -25,7 +25,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding  # noqa: E402
 
 from repro.configs import OptimizerConfig, get_config  # noqa: E402
-from repro.launch.mesh import use_mesh  # noqa: E402
 from repro.launch.roofline import roofline_from_compiled  # noqa: E402
 from repro.launch.topology import Topology  # noqa: E402
 from repro.models.model import init_model  # noqa: E402
@@ -135,7 +134,7 @@ def main():
                           is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
 
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(train_step).lower(
             stage_sh, shared_sh, opt_in, batch, jax.ShapeDtypeStruct((), jnp.int32)
         )

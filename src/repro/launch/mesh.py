@@ -1,35 +1,24 @@
-"""Production mesh construction + JAX version-compat shims.
+"""Production mesh construction.
 
 Defined as functions (never module-level constants) so importing this module
 never touches jax device state — smoke tests must keep seeing 1 CPU device;
 only `dryrun.py` forces 512 host devices.
 
-``make_mesh_compat`` / ``use_mesh`` paper over the moving mesh API surface:
-``jax.sharding.AxisType`` and ``jax.set_mesh`` only exist on newer JAX
-releases, while older ones spell the context manager ``with mesh:``. Every
-mesh in the repo is built through these two helpers so a single site absorbs
-the version skew.
+Every mesh is built with ``Auto`` axis types (`auto_axes`): the sharding
+rules and the shard_map pipeline are written for XLA's sharding propagation,
+not for sharding-in-types (`jax.make_mesh` defaults to ``Explicit``).
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Dict, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
-def make_mesh_compat(shape: Tuple[int, ...], axis_names: Sequence[str]) -> Mesh:
-    """`jax.make_mesh` with Auto axis types where the installed JAX has them."""
-    try:
-        from jax.sharding import AxisType
-
-        return jax.make_mesh(
-            tuple(shape), tuple(axis_names),
-            axis_types=(AxisType.Auto,) * len(axis_names),
-        )
-    except ImportError:
-        return jax.make_mesh(tuple(shape), tuple(axis_names))
+def auto_axes(n: int) -> Tuple[AxisType, ...]:
+    """``axis_types`` for an n-axis `jax.make_mesh` under propagation."""
+    return (AxisType.Auto,) * n
 
 
 def make_process_mesh(shape: Tuple[int, ...], axis_names: Sequence[str]) -> Mesh:
@@ -51,34 +40,20 @@ def make_process_mesh(shape: Tuple[int, ...], axis_names: Sequence[str]) -> Mesh
         raise ValueError(
             f"{devices.size} global devices do not fill mesh shape {shape}"
         )
-    return Mesh(devices.reshape(tuple(shape)), tuple(axis_names))
-
-
-def use_mesh(mesh: Mesh):
-    """Context manager installing `mesh` as the ambient mesh.
-
-    Newer JAX: `jax.set_mesh`; older: the Mesh object itself is the context
-    manager. (`jax.sharding.use_mesh` existed briefly in between.)
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext()  # pragma: no cover — future-proofing
+    return Mesh(devices.reshape(tuple(shape)), tuple(axis_names),
+                axis_types=auto_axes(len(axis_names)))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=auto_axes(len(axes)))
 
 
 def make_smoke_mesh() -> Mesh:
     """Single-device mesh with the production axis names (for tests)."""
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=auto_axes(2))
 
 
 def mesh_shape_dict(mesh: Mesh) -> Dict[str, int]:

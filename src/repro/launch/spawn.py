@@ -1,5 +1,11 @@
 """Single-machine multi-controller launcher (CI-sized `jax.distributed`).
 
+A CPU-only rehearsal: every worker runs on XLA:CPU (``JAX_PLATFORMS=cpu``
+is forced), since one chip belongs to one process and N workers on one
+accelerator host would contend for it. On TPU hosts, run one
+``repro.launch.train`` per host with ``--coordinator/--num-processes/
+--process-id`` instead.
+
 Forks N REAL OS processes, each running ``python -m repro.launch.train``
 under the ``REPRO_*`` env contract (`repro.launch.distributed`), with a
 fresh coordinator port on 127.0.0.1. This is the same code path a cluster
@@ -22,8 +28,8 @@ Worker env notes: the launcher strips any inherited
 ``--xla_force_host_platform_device_count`` from ``XLA_FLAGS`` (each worker
 re-derives its LOCAL share via `launch.devices.ensure_host_devices`; an
 outer test harness's global count would be wrong for a slab), forces
-``JAX_PLATFORMS=cpu`` unless already set, and prepends this tree's ``src``
-to ``PYTHONPATH`` so workers import the same checkout.
+``JAX_PLATFORMS=cpu``, and prepends this tree's ``src`` to ``PYTHONPATH``
+so workers import the same checkout.
 """
 from __future__ import annotations
 
@@ -60,7 +66,7 @@ def worker_env(num_processes: int, process_id: int, coordinator: str) -> dict:
     env[ENV_COORDINATOR] = coordinator
     env[ENV_NUM_PROCESSES] = str(num_processes)
     env[ENV_PROCESS_ID] = str(process_id)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" in flags:
         # an outer harness forced a GLOBAL device count; workers must force

@@ -4,7 +4,7 @@ Every entry point used to hand-roll its own mesh: `SpmdEngine` built a
 single-host (stage, data) mesh, `dryrun_pipeline.py` a fake 512-chip
 (pod, stage, data) mesh, and the benchmarks a third variant. `Topology` is
 the single owner of that decision: it names the axes, builds the mesh
-(through the version-compat shims in `repro.launch.mesh`), derives the
+(`jax.make_mesh` with Auto axis types), derives the
 PartitionSpecs for stage-stacked parameters and microbatched token streams,
 and tells the data pipeline how many host shards the global batch splits
 into. Everything downstream — the tick schedules' gradient reductions, the
@@ -198,9 +198,9 @@ class Topology:
     # -- mesh + specs --------------------------------------------------------
 
     def make_mesh(self) -> Mesh:
-        from repro.launch.mesh import make_mesh_compat, make_process_mesh
-
         import jax
+
+        from repro.launch.mesh import auto_axes, make_process_mesh
 
         if jax.process_count() > 1:
             # multi-controller: the device grid must be row-major so process
@@ -208,7 +208,8 @@ class Topology:
             # and checkpoint shard-ownership maps assume (jax.make_mesh may
             # permute devices for ICI locality)
             return make_process_mesh(self.shape, self.axis_names)
-        return make_mesh_compat(self.shape, self.axis_names)
+        return jax.make_mesh(self.shape, self.axis_names,
+                             axis_types=auto_axes(len(self.axis_names)))
 
     def stage_spec(self, ndim: int) -> P:
         """Stage-stacked leaf of rank ``ndim``: leading axis over `stage`."""

@@ -14,10 +14,9 @@ Two backends behind the same loop (`repro.engine`):
     `(pod, stage, data)` `Topology` (gradients all-reduce over
     ``("pod", "data")``, checkpoints save one arrays file per stage shard,
     and multi-pod runs load data host-sharded via
-    ``data.synthetic.sharded_batches``). On a CPU-only host the driver
-    forces ``pods*stages*data`` host devices automatically; on accelerator
-    machines with a different device count, re-run with
-    ``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=N``.
+    ``data.synthetic.sharded_batches``). The topology must use every
+    visible device: on a TPU host ``pods*stages*data`` is the chip count;
+    on a CPU-only host `main` forces that many host devices itself.
 
     The spmd backend also runs TRUE multi-controller: start N copies of this
     driver (``repro.launch.spawn`` does it on one machine) with either the
@@ -35,12 +34,22 @@ Two backends behind the same loop (`repro.engine`):
         --steps 300 --batch 8 --seq 256 --lr 1e-3 [--backend spmd]
 
 Checkpoints land under --ckpt-dir every --ckpt-every steps and training
-resumes from the latest one if present.
+resumes from the latest one if present. Compiled programs persist in JAX's
+compilation cache (`repro.launch.compile_cache`). `main` returns the engine,
+its final state and the loss curve, for callers that drive it in-process
+(`chip_smoke.py`).
 """
 from __future__ import annotations
 
 import argparse
 import math
+from typing import Any, List, NamedTuple
+
+
+class TrainRun(NamedTuple):
+    engine: Any
+    state: Any
+    losses: List[float]
 
 
 def parse_args(argv=None):
@@ -187,6 +196,10 @@ def main(argv=None):
         # already see the merged global device grid
         init_distributed(grid)
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     main_proc = is_main()
 
     from repro.configs import OptimizerConfig, get_config
@@ -247,16 +260,12 @@ def main(argv=None):
         except ValueError:
             topology = None
         if topology is None or topology.num_devices != n:
-            # the forced-host-device flag only affects the CPU platform (and
-            # only if it wasn't already set with a different count)
-            want = args.pods * args.stages * max(args.data_par, 1)
             raise SystemExit(
-                f"spmd backend: {n} global devices ({grid.describe()}) do "
-                f"not form a (pods={args.pods}, stages={args.stages}, "
-                f"data={args.data_par}) topology; re-run with "
-                f"JAX_PLATFORMS=cpu XLA_FLAGS="
-                f"--xla_force_host_platform_device_count="
-                f"{want // grid.num_processes} on each process"
+                f"spmd backend: the (pods={args.pods}, stages={args.stages}, "
+                f"data={args.data_par}) topology does not match the {n} "
+                f"{jax.devices()[0].platform} device(s) of this run "
+                f"({grid.describe()}); pods*stages*data must use every "
+                f"device"
             )
         M = args.microbatches or args.stages
         shards = topology.data_shards
@@ -356,9 +365,11 @@ def main(argv=None):
                   "use_kernels": args.use_kernels,
                   "data_async": args.data_async, "data_delay": data_delay},
     )
-    _, losses = run_loop(engine, data, loop_cfg, state=state, start_step=start_step)
+    state, losses = run_loop(engine, data, loop_cfg, state=state,
+                             start_step=start_step)
     if losses and main_proc:
         print(f"final loss {losses[-1]:.4f}")
+    return TrainRun(engine, state, losses)
 
 
 if __name__ == "__main__":

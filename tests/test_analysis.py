@@ -136,9 +136,7 @@ def test_dtype_policy_passes_f32_and_flags_injected_f64_leaf():
     clean = jax.make_jaxpr(lambda x: (x * 2).sum())(jnp.ones((4,), jnp.float32))
     assert check_dtype_policy(clean).passed
 
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64():
         leaky = jax.make_jaxpr(
             lambda x: (x.astype(jnp.float64) * 2).astype(jnp.float32).sum()
         )(jnp.ones((4,), jnp.float32))
@@ -238,7 +236,6 @@ from repro.analysis import (check_no_dot_outside_cond, check_stash_bound,
                             check_dtype_policy, check_collective_axes,
                             check_data_reduction, parse_collectives)
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 cfg = ModelConfig(num_layers=2, d_model=16, d_ff=24, vocab_size=96,
                   max_seq_len=32,
@@ -286,8 +283,8 @@ def f(x):
     z = jax.lax.psum(x, "stage")
     w = jax.lax.ppermute(x, "stage", [(0, 1)])
     return y + z + w
-sm = shard_map(f, mesh=mesh, in_specs=P("stage"), out_specs=P("stage"),
-               check_rep=False)
+sm = jax.shard_map(f, mesh=mesh, in_specs=P("stage"), out_specs=P("stage"),
+                   check_vma=False)
 hlo = jax.jit(sm).lower(jnp.zeros((2, 4))).compile().as_text()
 instrs = parse_collectives(hlo)
 res["hlo"] = {

@@ -301,7 +301,7 @@ import jax, jax.numpy as jnp, json
 from repro.configs.base import ModelConfig, AttentionConfig, BlockSpec, OptimizerConfig
 from repro.data import batches
 from repro.engine import LoopConfig, SimEngine, SpmdEngine, run_loop
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import auto_axes
 from repro.models import init_model
 from repro.optim.factory import build_optimizer
 
@@ -318,7 +318,7 @@ s_state = sim.init_state(params=params)
 _, sim_losses = run_loop(sim, batches(cfg, M * 2, 16, seed=0),
                          LoopConfig(steps=steps), state=s_state)
 
-mesh = make_mesh_compat((K, 1), ("stage", "data"))
+mesh = jax.make_mesh((K, 1), ("stage", "data"), axis_types=auto_axes(2))
 res = {"sim": sim_losses}
 for sched in ("fill_drain", "1f1b"):
     for async_grads in (False, True):
@@ -367,7 +367,7 @@ import jax, jax.numpy as jnp, json
 from repro.configs.base import ModelConfig, AttentionConfig, BlockSpec, OptimizerConfig
 from repro.data import batches
 from repro.engine import LoopConfig, SimEngine, SpmdEngine, run_loop
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import auto_axes
 from repro.models import init_model
 from repro.optim.factory import build_optimizer
 
@@ -376,7 +376,7 @@ cfg = ModelConfig(num_layers=4, d_model=32, d_ff=64, vocab_size=64, max_seq_len=
                   pattern=(BlockSpec("attn","dense"),), scan_layers=False)
 K, M, steps = 4, 4, 8
 params = init_model(jax.random.PRNGKey(0), cfg)
-mesh = make_mesh_compat((K, 1), ("stage", "data"))
+mesh = jax.make_mesh((K, 1), ("stage", "data"), axis_types=auto_axes(2))
 res = {}
 
 # stage-aware basis rotation, synchronous: the vectorized per-stage refresh
@@ -582,7 +582,7 @@ import jax, jax.numpy as jnp, json
 from repro.configs.base import ModelConfig, AttentionConfig, BlockSpec
 from repro.engine import make_pipeline_grad, stack_stage_params
 from repro.engine.schedules import SCHEDULE_INVARIANTS
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import auto_axes
 from repro.models import init_model
 from repro.analysis import (check_no_dot_outside_cond,
                             check_scan_body_constant_in_microbatches,
@@ -596,7 +596,7 @@ K = 4
 V = cfg.vocab_size
 params = init_model(jax.random.PRNGKey(0), cfg)
 stacked, shared = stack_stage_params(params, cfg, K)
-mesh = make_mesh_compat((K, 1), ("stage", "data"))
+mesh = jax.make_mesh((K, 1), ("stage", "data"), axis_types=auto_axes(2))
 
 def trace(sched, m):
     gf = make_pipeline_grad(cfg, mesh, K, m, schedule=sched)
@@ -655,3 +655,28 @@ def test_1f1b_jaxpr_and_activation_buffer_constant_in_microbatches():
     # and the input stash never exceeds its 2K-1 slots
     assert res["1f1b"]["stash"]["passed"], res["1f1b"]["stash"]
     assert 2 * 4 - 1 in res["1f1b"]["stash"]["data"]["slot_counts"], res
+
+
+
+def test_spmd_step_compiles_once_and_executable_is_the_one_run(tmp_path):
+    """The committed outputs of step t feed step t+1 without a second
+    compile, a state restored from a checkpoint reuses the same program,
+    and `executable` returns the program `step` runs."""
+    from repro.checkpoint import load_checkpoint
+    from repro.engine import SpmdEngine
+
+    ocfg = OptimizerConfig(name="basis_rotation", learning_rate=1e-3,
+                           total_steps=4, rotation_freq=2)
+    eng = SpmdEngine(CFG, ocfg, num_stages=1)
+    st = eng.init_state(key=jax.random.PRNGKey(0))
+    data = batches(CFG, 4, 16, seed=0)
+    for t in range(3):
+        st, loss, _ = eng.step(st, next(data), t)
+    eng.checkpoint_job(str(tmp_path), st, step=3)()
+    tree, step, _ = load_checkpoint(str(tmp_path))
+    assert step == 3
+    st, loss, _ = eng.step(eng.load_state(tree), next(data), 3)
+    assert np.isfinite(float(loss))
+    (programs,) = eng._executables.values()
+    assert len(programs) == 1
+    assert eng.executable(st, next(data), 4) is programs[0]

@@ -526,3 +526,19 @@ def test_token_and_activation_specs():
     assert tokens_pspec(256, ms3) == P(("pod", "data"), None)
     spec = generic_activation_pspec((128, 8, 32768, 128), MESH, batch_dim=0)
     assert spec[0] in ("data", ("data",)) and "model" in spec
+
+
+def test_compile_cache_follows_env_and_skips_cpu(monkeypatch):
+    """A set JAX_COMPILATION_CACHE_DIR is left to JAX; otherwise the cache
+    is the checkout's fixed `.jax_cache`, and XLA:CPU stays uncached."""
+    from repro.launch import compile_cache as cc
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(cc.ENV_VAR, "/elsewhere")
+    assert cc.use_compile_cache() == "/elsewhere"
+    monkeypatch.delenv(cc.ENV_VAR)
+    assert jax.default_backend() == "cpu"
+    assert cc.use_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+    assert cc.REPO_CACHE_DIR == cc.REPO_ROOT / ".jax_cache"
+    assert (cc.REPO_ROOT / "pyproject.toml").is_file()

@@ -84,7 +84,7 @@ import sys
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, json
 from repro.configs.base import ModelConfig, AttentionConfig, BlockSpec
-from repro.launch.mesh import make_mesh_compat, use_mesh
+from repro.launch.mesh import auto_axes
 from repro.models import init_model
 from repro.models.model import loss_fn
 from repro.pipeline.spmd import stack_stage_params, make_pipeline_grad, make_pipeline_loss
@@ -95,12 +95,12 @@ cfg = ModelConfig(num_layers=4, d_model=32, d_ff=64, vocab_size=64, max_seq_len=
 params = init_model(jax.random.PRNGKey(0), cfg)
 K, M = 4, 4
 stacked, shared = stack_stage_params(params, cfg, K)
-mesh = make_mesh_compat((K, 2), ("stage", "data"))
+mesh = jax.make_mesh((K, 2), ("stage", "data"), axis_types=auto_axes(2))
 toks = jax.random.randint(jax.random.PRNGKey(1), (M, 4, 16), 0, 64)
 labels = jax.random.randint(jax.random.PRNGKey(2), (M, 4, 16), 0, 64)
 batch = {"tokens": toks, "labels": labels}
 grad_fn = make_pipeline_grad(cfg, mesh, K, M)
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     loss, (gs, gsh) = jax.jit(grad_fn)(stacked, shared, batch)
 flat = {"tokens": toks.reshape(-1, 16), "labels": labels.reshape(-1, 16)}
 (ref_loss, _), ref_g = jax.value_and_grad(loss_fn, has_aux=True)(params, cfg, flat)
@@ -173,13 +173,13 @@ import jax, jax.numpy as jnp, json
 from repro.configs.base import ModelConfig, AttentionConfig, BlockSpec, OptimizerConfig
 from repro.data import batches
 from repro.engine import SpmdEngine, LoopConfig, run_loop
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import auto_axes
 
 cfg = ModelConfig(num_layers=4, d_model=32, d_ff=64, vocab_size=64, max_seq_len=64,
                   attention=AttentionConfig(num_heads=2, num_kv_heads=2, head_dim=16),
                   pattern=(BlockSpec("attn","dense"),), scan_layers=False)
 K, M = 4, 4
-mesh = make_mesh_compat((K, 2), ("stage", "data"))
+mesh = jax.make_mesh((K, 2), ("stage", "data"), axis_types=auto_axes(2))
 ocfg = OptimizerConfig(name="basis_rotation", learning_rate=3e-3, total_steps=25,
                        rotation_freq=5, schedule="constant")
 engine = SpmdEngine(cfg, ocfg, num_stages=K, num_microbatches=M, mesh=mesh)
