@@ -82,8 +82,11 @@ def _refresh_ops(g, m, ops, freq: FreqSpec, step, source: str, beta2: float):
     """
 
     def do_refresh(o):
-        Uo, Vo, Lo, Ro = o
-        return refresh_basis(g, m, Uo, Vo, Lo, Ro, source, beta2)
+        # the scope sits inside the branch: ops of the cond itself (operand
+        # copies, the identity branch of a plain step) stay outside it
+        with jax.named_scope("refresh"):
+            Uo, Vo, Lo, Ro = o
+            return refresh_basis(g, m, Uo, Vo, Lo, Ro, source, beta2)
 
     if isinstance(freq, tuple):
         K = len(freq)
@@ -105,7 +108,8 @@ def _refresh_ops(g, m, ops, freq: FreqSpec, step, source: str, beta2: float):
                     return None
                 return jnp.where(mask.reshape((K,) + (1,) * (old.ndim - 1)), n, old)
 
-            return tuple(sel(n, old) for n, old in zip(new, o))
+            with jax.named_scope("refresh"):
+                return tuple(sel(n, old) for n, old in zip(new, o))
 
         return jax.lax.cond(jnp.any(mask), masked_refresh, lambda o: o, ops)
 
@@ -165,18 +169,23 @@ def basis_rotation_adam(
                     f, step, source, beta2,
                 )
                 if kops is not None:
-                    g_rot = kops.two_sided_rotate(g, U, V, transpose=True)
-                    m_rot = kops.two_sided_rotate(m, U, V, transpose=True)
+                    with jax.named_scope("rotate"):
+                        g_rot = kops.two_sided_rotate(g, U, V, transpose=True)
+                        m_rot = kops.two_sided_rotate(m, U, V, transpose=True)
                     step_rot, v = kops.adam_scale(
                         g_rot, m_rot, st["v"], beta2, eps, bc1, bc2
                     )
-                    upd = -lr * kops.two_sided_rotate(step_rot, U, V, transpose=False)
+                    with jax.named_scope("rotate"):
+                        back = kops.two_sided_rotate(step_rot, U, V, transpose=False)
                 else:
-                    g_rot = rotate(g, U, V)
-                    m_rot = rotate(m, U, V)
+                    with jax.named_scope("rotate"):
+                        g_rot = rotate(g, U, V)
+                        m_rot = rotate(m, U, V)
                     v = beta2 * st["v"] + (1 - beta2) * jnp.square(g_rot)
                     step_rot = (m_rot / bc1) / (jnp.sqrt(v / bc2) + eps)
-                    upd = -lr * unrotate(step_rot, U, V)
+                    with jax.named_scope("rotate"):
+                        back = unrotate(step_rot, U, V)
+                upd = -lr * back
                 nst["v"] = v
                 if U is not None:
                     nst["U"] = U

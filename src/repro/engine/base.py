@@ -15,6 +15,12 @@ either backend (launch driver, benchmarks, examples, tests):
     state = engine.init_state(params=..., key=...)
     state, loss, metrics = engine.step(state, batch, t)
 
+Each `step` call marks its host work with `jax.profiler.TraceAnnotation`
+spans, which record nothing while no profiler runs:
+``engine.args`` (building the step's arguments), ``engine.lookup`` (finding
+the compiled step for them, `compiled_step_for`), ``engine.compile`` (nested
+in it, on a miss only) and ``engine.launch`` (the executable call).
+
 `EngineState` is deliberately a plain container: `params` and `opt_state` are
 backend-specific pytrees (SPMD keeps the stage-stacked representation), and
 `history` is the sim backend's no-stash snapshot window. `checkpoint_tree` /
@@ -27,6 +33,8 @@ import abc
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
+
 
 @dataclass
 class EngineState:
@@ -37,6 +45,34 @@ class EngineState:
     # deferred cross-replica gradient reductions, oldest first. ``None``
     # whenever the data axis is synchronous.
     data_fifo: Optional[List[Any]] = None
+
+
+def compiled_step_for(known: Dict, jitted: Any, args: Tuple):
+    """The compiled step in `known` for these arguments, compiled on first use.
+
+    The state `init_state` builds is uncommitted, so the compiler lays it
+    out, and the step returns it in that same layout. Reusing the executable
+    whose input shardings the arguments already have keeps those committed
+    outputs from compiling the step a second time, as a plain `jax.jit` call
+    would. Arguments laid out otherwise (a state restored onto another mesh
+    layout) get an executable of their own.
+    """
+    with jax.profiler.TraceAnnotation("engine.lookup"):
+        leaves, treedef = jax.tree.flatten(args)
+        key = (treedef,
+               tuple((getattr(a, "shape", ()), getattr(a, "dtype", type(a)))
+                     for a in leaves))
+        programs = known.setdefault(key, [])
+        for exe in programs:
+            want = jax.tree.leaves(exe.input_shardings[0])
+            if all(not isinstance(a, jax.Array)
+                   or a.sharding.is_equivalent_to(w, a.ndim)
+                   for a, w in zip(leaves, want)):
+                return exe
+        with jax.profiler.TraceAnnotation("engine.compile"):
+            exe = jitted.lower(*args).compile()
+        programs.append(exe)
+        return exe
 
 
 class PipelineEngine(abc.ABC):
@@ -53,6 +89,12 @@ class PipelineEngine(abc.ABC):
         self, state: EngineState, batch: Dict, t: int
     ) -> Tuple[EngineState, Any, Dict]:
         """One optimizer step. Returns (new_state, loss, metrics)."""
+
+    @abc.abstractmethod
+    def executable(self, state: EngineState, batch: Dict, t: int = 0) -> Any:
+        """The compiled program `step` runs on these arguments. Its
+        ``as_text()`` is the optimized HLO, whose ``op_name`` metadata
+        carries the step's named scopes."""
 
     # -- checkpointing -----------------------------------------------------
 

@@ -25,6 +25,10 @@ Host I/O stays off the step critical path (DESIGN.md §11):
   job inline (bit-identical output either way — the writer is drained
   before `run_loop` returns, and any writer exception re-raises on the
   loop thread).
+
+Under `jax.profiler`, each iteration is a `StepTraceAnnotation("train",
+step_num=t)` (TensorBoard's step-time view reads it) and each checkpoint
+snapshot a ``loop.ckpt`` span.
 """
 from __future__ import annotations
 
@@ -35,6 +39,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+
+import jax
 
 from repro.engine.base import EngineState, PipelineEngine
 
@@ -212,42 +218,47 @@ def run_loop(
     try:
         t0 = time.time()
         for t in range(start_step, cfg.steps):
-            batch = next(data_iter)
-            state, loss, metrics = engine.step(state, batch, t)
-            pending.append(loss)
-            if main and cfg.log_every and t % cfg.log_every == 0:
-                flush_losses()
-                extra = (
-                    f"  ce {float(metrics['ce']):.4f}" if "ce" in metrics else ""
+            with jax.profiler.StepTraceAnnotation("train", step_num=t):
+                batch = next(data_iter)
+                state, loss, metrics = engine.step(state, batch, t)
+                pending.append(loss)
+                if main and cfg.log_every and t % cfg.log_every == 0:
+                    flush_losses()
+                    extra = (
+                        f"  ce {float(metrics['ce']):.4f}" if "ce" in metrics else ""
+                    )
+                    print(f"step {t:5d}  loss {losses[-1]:.4f}{extra}"
+                          f"  ({time.time() - t0:.1f}s)")
+                wrote_ckpt = (
+                    cfg.ckpt_dir and cfg.ckpt_every and (t + 1) % cfg.ckpt_every == 0
                 )
-                print(f"step {t:5d}  loss {losses[-1]:.4f}{extra}"
-                      f"  ({time.time() - t0:.1f}s)")
-            wrote_ckpt = (
-                cfg.ckpt_dir and cfg.ckpt_every and (t + 1) % cfg.ckpt_every == 0
-            )
-            if wrote_ckpt:
-                # the engine owns the on-disk format (SpmdEngine writes one
-                # arrays file per stage shard instead of gathering to host,
-                # and in multi-process runs each process writes only its own
-                # shards); the snapshot half runs here, the write half on
-                # the writer thread
-                submit(engine.checkpoint_job(cfg.ckpt_dir, state, step=t + 1))
-            # metrics are flushed at every checkpoint too, so the metrics
-            # file never lags a checkpoint a later resume will restart from
-            # (a lagging file would forfeit its pre-resume series at merge
-            # time; the serial writer preserves ckpt-then-metrics order)
-            if main and cfg.out_path and (
-                wrote_ckpt or (t + 1) % max(cfg.log_every, 1) == 0
-            ):
-                flush_losses()
-                snapshot = prefix + losses
-                done = t + 1
-                submit(lambda s=snapshot, d=done: _write_metrics(
-                    cfg, s, d, prefix_start
-                ))
+                if wrote_ckpt:
+                    # the engine owns the on-disk format (SpmdEngine writes one
+                    # arrays file per stage shard instead of gathering to host,
+                    # and in multi-process runs each process writes only its own
+                    # shards); the snapshot half runs here, the write half on
+                    # the writer thread
+                    with jax.profiler.TraceAnnotation("loop.ckpt"):
+                        job = engine.checkpoint_job(cfg.ckpt_dir, state, step=t + 1)
+                    submit(job)
+                # metrics are flushed at every checkpoint too, so the metrics
+                # file never lags a checkpoint a later resume will restart from
+                # (a lagging file would forfeit its pre-resume series at merge
+                # time; the serial writer preserves ckpt-then-metrics order)
+                if main and cfg.out_path and (
+                    wrote_ckpt or (t + 1) % max(cfg.log_every, 1) == 0
+                ):
+                    flush_losses()
+                    snapshot = prefix + losses
+                    done = t + 1
+                    submit(lambda s=snapshot, d=done: _write_metrics(
+                        cfg, s, d, prefix_start
+                    ))
         flush_losses()
         if cfg.ckpt_dir:
-            submit(engine.checkpoint_job(cfg.ckpt_dir, state, step=cfg.steps))
+            with jax.profiler.TraceAnnotation("loop.ckpt"):
+                job = engine.checkpoint_job(cfg.ckpt_dir, state, step=cfg.steps)
+            submit(job)
         if main and cfg.out_path:
             final = prefix + losses
             submit(lambda: _write_metrics(cfg, final, cfg.steps, prefix_start))

@@ -102,7 +102,8 @@ def _stage_apply_fn(cfg: ModelConfig):
             h, _ = block_train(w, h, cfg, spec)
             return h, None
 
-        x, _ = jax.lax.scan(body, x, wk)
+        with jax.named_scope("blocks"):
+            x, _ = jax.lax.scan(body, x, wk)
         return x
 
     return stage_f
@@ -111,10 +112,11 @@ def _stage_apply_fn(cfg: ModelConfig):
 def _embed_fn(cfg: ModelConfig):
     def embed_f(shared_raw, tokens_m):
         sh = cast_params(shared_raw, cfg.compute_dtype)
-        emb = _embed(sh, cfg, tokens_m)  # (mb, S, d)
-        if cfg.learnable_pos_emb:
-            emb = emb + sh["pos_emb"][: tokens_m.shape[-1]].astype(emb.dtype)
-        return emb
+        with jax.named_scope("embed"):
+            emb = _embed(sh, cfg, tokens_m)  # (..., S, d)
+            if cfg.learnable_pos_emb:
+                emb = emb + sh["pos_emb"][: tokens_m.shape[-1]].astype(emb.dtype)
+            return emb
 
     return embed_f
 
@@ -122,9 +124,10 @@ def _embed_fn(cfg: ModelConfig):
 def _head_fn(cfg: ModelConfig):
     def head_f(shared_raw, h, labels_m):
         sh = cast_params(shared_raw, cfg.compute_dtype)
-        x = apply_norm(sh["final_norm"], h)
-        logits = _logits(sh, cfg, x)  # (mb, S, V)
-        return cross_entropy(logits, labels_m)
+        with jax.named_scope("head"):
+            x = apply_norm(sh["final_norm"], h)
+            logits = _logits(sh, cfg, x)  # (..., S, V)
+            return cross_entropy(logits, labels_m)
 
     return head_f
 
@@ -148,6 +151,8 @@ def make_fill_drain_loss(
     """
     M = num_microbatches
     stage_f = _stage_apply_fn(cfg)
+    embed_f = _embed_fn(cfg)
+    head_f = _head_fn(cfg)
 
     def per_device(stage_params, shared, tokens, labels):
         # stage_params leaves arrive as (1, per, ...) local slices
@@ -157,9 +162,7 @@ def make_fill_drain_loss(
         K = num_stages
         mb, S = tokens.shape[1], tokens.shape[2]
 
-        emb = _embed(shared_c, cfg, tokens)  # (M, mb, S, d)
-        if cfg.learnable_pos_emb:
-            emb = emb + shared_c["pos_emb"][:S].astype(emb.dtype)
+        emb = embed_f(shared_c, tokens)  # (M, mb, S, d)
 
         d = emb.shape[-1]
         zeros = jnp.zeros((mb, S, d), emb.dtype)
@@ -185,15 +188,14 @@ def make_fill_drain_loss(
             out = jax.lax.dynamic_update_index_in_dim(
                 out, jnp.where(collect, h, cur), idx, axis=0
             )
-            recv = jax.lax.ppermute(h, stage_axis, fwd_perm)
+            with jax.named_scope("exchange"):
+                recv = jax.lax.ppermute(h, stage_axis, fwd_perm)
             return (recv, out), None
 
         ticks = jnp.arange(M + K - 1)
         (_, out_buf), _ = jax.lax.scan(tick, (zeros, out_buf), ticks)
 
-        x = apply_norm(shared_c["final_norm"], out_buf)
-        logits = _logits(shared_c, cfg, x)  # (M, mb, S, V)
-        ce = cross_entropy(logits, labels)
+        ce = head_f(shared_c, out_buf, labels)  # logits (M, mb, S, V)
         is_last = (k == K - 1).astype(jnp.float32)
         # only the last stage's loss is real; psum over stages, mean over the
         # data axes (a tuple covers the multi-pod (pod, data) case)
@@ -246,6 +248,8 @@ def make_fill_drain_local_grad(
     """
     M = num_microbatches
     stage_f = _stage_apply_fn(cfg)
+    embed_f = _embed_fn(cfg)
+    head_f = _head_fn(cfg)
 
     def per_device(stage_params, shared, tokens, labels):
         k = jax.lax.axis_index(stage_axis)
@@ -258,9 +262,7 @@ def make_fill_drain_local_grad(
             wk_raw = jax.tree.map(lambda x: x[0], stage_params)
             shared_c = cast_params(shared, cfg.compute_dtype)
 
-            emb = _embed(shared_c, cfg, tokens)  # (M, mb, S, d)
-            if cfg.learnable_pos_emb:
-                emb = emb + shared_c["pos_emb"][:S].astype(emb.dtype)
+            emb = embed_f(shared_c, tokens)  # (M, mb, S, d)
 
             d = emb.shape[-1]
             zeros = jnp.zeros((mb, S, d), emb.dtype)
@@ -284,15 +286,14 @@ def make_fill_drain_local_grad(
                 out = jax.lax.dynamic_update_index_in_dim(
                     out, jnp.where(collect, h, cur), idx, axis=0
                 )
-                recv = jax.lax.ppermute(h, stage_axis, fwd_perm)
+                with jax.named_scope("exchange"):
+                    recv = jax.lax.ppermute(h, stage_axis, fwd_perm)
                 return (recv, out), None
 
             ticks = jnp.arange(M + K - 1)
             (_, out_buf), _ = jax.lax.scan(tick, (zeros, out_buf), ticks)
 
-            x = apply_norm(shared_c["final_norm"], out_buf)
-            logits = _logits(shared_c, cfg, x)  # (M, mb, S, V)
-            ce = cross_entropy(logits, labels)
+            ce = head_f(shared_c, out_buf, labels)  # logits (M, mb, S, V)
             is_last = (k == K - 1).astype(jnp.float32)
             # per-device masked loss, NOT stage-psum'd: psum transposes to
             # psum, so differentiating through an in-body stage psum would
@@ -475,8 +476,9 @@ def make_1f1b_grad(
             )
             loss_acc = loss_acc + head_on * ce / M
 
-            fwd_recv = jax.lax.ppermute(h, stage_axis, fwd_perm)
-            bwd_recv = jax.lax.ppermute(dx, stage_axis, bwd_perm)
+            with jax.named_scope("exchange"):
+                fwd_recv = jax.lax.ppermute(h, stage_axis, fwd_perm)
+                bwd_recv = jax.lax.ppermute(dx, stage_axis, bwd_perm)
             return (fwd_recv, bwd_recv, stash, g_stage, g_shared, loss_acc), None
 
         ticks = jnp.arange(M + 2 * (K - 1))

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.engine.base import EngineState, PipelineEngine
+from repro.engine.base import EngineState, PipelineEngine, compiled_step_for
 from repro.optim.base import Optimizer
 
 
@@ -49,6 +49,7 @@ class SimEngine(PipelineEngine):
             self.max_age = max(
                 int(d) for d in jax.tree_util.tree_leaves(delays_tree)
             )
+        self._executables: Dict = {}
 
     def init_state(self, params: Any = None, key: Any = None) -> EngineState:
         if params is None:
@@ -58,9 +59,7 @@ class SimEngine(PipelineEngine):
                                 self.cfg)
         return EngineState(params=params, opt_state=self.optimizer.init(params))
 
-    def step(
-        self, state: EngineState, batch: Dict, t: int
-    ) -> Tuple[EngineState, Any, Dict]:
+    def _step_args(self, state: EngineState, batch: Dict, t: int) -> Tuple:
         from repro.pipeline.simulate import stale_forward_params
 
         fwd_hist = (
@@ -68,9 +67,21 @@ class SimEngine(PipelineEngine):
             if self.no_stash
             else 0
         )
-        params, opt_state, loss, metrics = self._step_fn(
-            state.params, state.opt_state, fwd_hist, batch, jnp.int32(t)
-        )
+        return state.params, state.opt_state, fwd_hist, batch, jnp.int32(t)
+
+    def executable(self, state: EngineState, batch: Dict, t: int = 0):
+        """The compiled program `step` runs on these arguments."""
+        return compiled_step_for(self._executables, self._step_fn,
+                                 self._step_args(state, batch, t))
+
+    def step(
+        self, state: EngineState, batch: Dict, t: int
+    ) -> Tuple[EngineState, Any, Dict]:
+        with jax.profiler.TraceAnnotation("engine.args"):
+            args = self._step_args(state, batch, t)
+        exe = compiled_step_for(self._executables, self._step_fn, args)
+        with jax.profiler.TraceAnnotation("engine.launch"):
+            params, opt_state, loss, metrics = exe(*args)
         history = state.history
         if self.no_stash and self.max_age:
             history = (history + [params])[-(self.max_age + 1):]

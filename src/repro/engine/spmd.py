@@ -38,7 +38,7 @@ from repro.configs.base import (
     OptimizerConfig,
     PrecisionPolicy,
 )
-from repro.engine.base import EngineState, PipelineEngine
+from repro.engine.base import EngineState, PipelineEngine, compiled_step_for
 from repro.engine.schedules import make_fill_drain_loss, make_schedule_grad
 from repro.launch.topology import Topology
 from repro.pipeline.partition import FIRST_STAGE_SHARED, stage_context_for_stacked
@@ -282,12 +282,14 @@ class SpmdEngine(PipelineEngine):
         def _step(stacked, shared, opt_state, batch, t):
             loss, grads = self.grad_fn(stacked, shared, batch)
             if grad_clip:
-                grads = clip_by_global_norm(grads, grad_clip)
-            updates, opt_state = self.opt.update(
-                grads, opt_state, (stacked, shared), t
-            )
-            stacked = apply_updates(stacked, updates[0])
-            shared = apply_updates(shared, updates[1])
+                with jax.named_scope("clip"):
+                    grads = clip_by_global_norm(grads, grad_clip)
+            with jax.named_scope("opt"):
+                updates, opt_state = self.opt.update(
+                    grads, opt_state, (stacked, shared), t
+                )
+                stacked = apply_updates(stacked, updates[0])
+                shared = apply_updates(shared, updates[1])
             return stacked, shared, opt_state, loss
 
         if self._data_eff:
@@ -303,12 +305,14 @@ class SpmdEngine(PipelineEngine):
                 loss_r, local = self._local_grad_fn(stacked, shared, batch)
                 grads = gbar
                 if grad_clip:
-                    grads = clip_by_global_norm(grads, grad_clip)
-                updates, opt_state = self.opt.update(
-                    grads, opt_state, (stacked, shared), t
-                )
-                stacked = apply_updates(stacked, updates[0])
-                shared = apply_updates(shared, updates[1])
+                    with jax.named_scope("clip"):
+                        grads = clip_by_global_norm(grads, grad_clip)
+                with jax.named_scope("opt"):
+                    updates, opt_state = self.opt.update(
+                        grads, opt_state, (stacked, shared), t
+                    )
+                    stacked = apply_updates(stacked, updates[0])
+                    shared = apply_updates(shared, updates[1])
                 return stacked, shared, opt_state, loss_r, local
 
             # reduce program: mean over the leading replica axis — lowered
@@ -472,41 +476,21 @@ class SpmdEngine(PipelineEngine):
                     batch, jnp.int32(t))
         return stacked, shared, state.opt_state, batch, jnp.int32(t)
 
-    def _executable(self, args: Tuple):
-        """The compiled step for these arguments, compiled on first use.
-
-        The state `init_state` builds is uncommitted, so the compiler lays
-        it out, and the step returns it in that same layout. Reusing the
-        executable whose input shardings the arguments already have keeps
-        those committed outputs from compiling the step a second time, as a
-        plain `jax.jit` call would. Arguments laid out otherwise (a state
-        restored onto another mesh layout) get an executable of their own.
-        """
-        leaves = jax.tree.leaves(args)
-        key = (jax.tree.structure(args),
-               tuple((a.shape, a.dtype) for a in leaves))
-        known = self._executables.setdefault(key, [])
-        for exe in known:
-            want = jax.tree.leaves(exe.input_shardings[0])
-            if all(not isinstance(a, jax.Array)
-                   or a.sharding.is_equivalent_to(w, a.ndim)
-                   for a, w in zip(leaves, want)):
-                return exe
-        exe = self._jit_step.lower(*args).compile()
-        known.append(exe)
-        return exe
-
     def executable(self, state: EngineState, batch: Dict, t: int = 0):
         """The compiled program `step` runs on these arguments; its
         ``as_text()`` shows which kernels the device runs."""
-        return self._executable(self._step_args(state, batch, t))
+        return compiled_step_for(self._executables, self._jit_step,
+                                 self._step_args(state, batch, t))
 
     def step(
         self, state: EngineState, batch: Dict, t: int
     ) -> Tuple[EngineState, Any, Dict]:
-        args = self._step_args(state, batch, t)
+        with jax.profiler.TraceAnnotation("engine.args"):
+            args = self._step_args(state, batch, t)
+        exe = compiled_step_for(self._executables, self._jit_step, args)
         if not self._data_eff:
-            stacked, shared, opt_state, loss = self._executable(args)(*args)
+            with jax.profiler.TraceAnnotation("engine.launch"):
+                stacked, shared, opt_state, loss = exe(*args)
             return (
                 EngineState((stacked, shared), opt_state),
                 loss,
@@ -518,10 +502,9 @@ class SpmdEngine(PipelineEngine):
         # the reduce result for D more steps, the all-reduce overlaps with
         # the next steps' compute instead of serializing each one.
         fifo = list(state.data_fifo[1:])
-        stacked, shared, opt_state, loss_r, local = self._executable(args)(
-            *args
-        )
-        loss, reduced = self._jit_reduce(loss_r, local)
+        with jax.profiler.TraceAnnotation("engine.launch"):
+            stacked, shared, opt_state, loss_r, local = exe(*args)
+            loss, reduced = self._jit_reduce(loss_r, local)
         fifo.append(reduced)
         return (
             EngineState((stacked, shared), opt_state, data_fifo=fifo),

@@ -92,5 +92,6 @@ def fused_adam_scale(
             jax.ShapeDtypeStruct((Rp, Cp), jnp.float32),
         ],
         interpret=interpret,
+        name="adam_step",
     )(scalars, g, m, v)
     return step[:R, :C], v_new[:R, :C]

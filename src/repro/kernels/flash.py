@@ -159,6 +159,7 @@ def _flash_forward(cfg, qf, kf, vf):
         ],
         scratch_shapes=_scratch([(bq, 1), (bq, 1), (bq, dh)]),
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return o, L[..., 0]
 
@@ -263,6 +264,7 @@ def _flash_backward(cfg, qf, kf, vf, o, L, do):
         out_shape=jax.ShapeDtypeStruct((BH, Sp, dh), qf.dtype),
         scratch_shapes=_scratch([(bq, dh)]),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, do, L, D)
 
     # transposed grid: K blocks outer, Q innermost, accumulate over queries
@@ -284,6 +286,7 @@ def _flash_backward(cfg, qf, kf, vf, o, L, do):
         ],
         scratch_shapes=_scratch([(bk, dh), (bk, dh)]),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, kf, vf, do, L, D)
     return dq, dk, dv
 

@@ -87,5 +87,6 @@ def matmul(
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="matmul",
     )(a_p, b_p)
     return out[:M, :N]

@@ -42,6 +42,7 @@ its final state and the loss curve, for callers that drive it in-process
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import math
 from typing import Any, List, NamedTuple
 
@@ -111,6 +112,14 @@ def parse_args(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=200)
     ap.add_argument("--out", default=None, help="write the loss curve as JSON")
+    ap.add_argument("--profile-dir", "--profile_dir", default=None,
+                    help="write a jax.profiler trace of --profile-steps here "
+                         "(open it with TensorBoard's profile plugin)")
+    ap.add_argument("--profile-steps", "--profile_steps", default=None,
+                    metavar="A:B",
+                    help="with --profile-dir: trace steps [A, B) and end "
+                         "the run there; steps before A run untraced, so "
+                         "compiles stay out of the trace")
     ap.add_argument("--coordinator", default=None,
                     help="spmd backend: jax.distributed coordinator "
                          "host:port (default: the REPRO_COORDINATOR env "
@@ -124,8 +133,24 @@ def parse_args(argv=None):
     return ap.parse_args(argv)
 
 
+def profile_window(spec: str, steps: int):
+    """`A:B` -> (A, B), the traced steps [A, B) of a `steps`-step run."""
+    try:
+        a, b = (int(x) for x in spec.split(":"))
+    except ValueError:
+        raise SystemExit(f"--profile-steps takes A:B, got {spec!r}")
+    if not 0 <= a < b <= steps:
+        raise SystemExit(f"--profile-steps {spec} must satisfy 0 <= A < B <= "
+                         f"--steps {steps}")
+    return a, b
+
+
 def main(argv=None):
     args = parse_args(argv)
+    if (args.profile_dir is None) != (args.profile_steps is None):
+        raise SystemExit("--profile-dir and --profile-steps go together")
+    window = (profile_window(args.profile_steps, args.steps)
+              if args.profile_dir else None)
     if args.data_delay is not None and not args.data_async:
         raise SystemExit("--data-delay only applies under --data-async")
     if args.data_async and args.sync:
@@ -365,8 +390,29 @@ def main(argv=None):
                   "use_kernels": args.use_kernels,
                   "data_async": args.data_async, "data_delay": data_delay},
     )
-    state, losses = run_loop(engine, data, loop_cfg, state=state,
-                             start_step=start_step)
+    if window is None:
+        state, losses = run_loop(engine, data, loop_cfg, state=state,
+                                 start_step=start_step)
+    else:
+        # [start, A) untraced, then [A, B) under the profiler; the loop
+        # itself knows nothing of profiling
+        a, b = window
+        if start_step >= b:
+            raise SystemExit(f"resumed at step {start_step}, past the "
+                             f"profiled steps {args.profile_steps}")
+        losses = []
+        if start_step < a:
+            state, losses = run_loop(engine, data,
+                                     dataclasses.replace(loop_cfg, steps=a),
+                                     state=state, start_step=start_step)
+        with jax.profiler.trace(args.profile_dir):
+            state, part = run_loop(engine, data,
+                                   dataclasses.replace(loop_cfg, steps=b),
+                                   state=state, start_step=max(start_step, a))
+        losses += part
+        if main_proc:
+            print(f"profiled steps [{max(start_step, a)}, {b}) into "
+                  f"{args.profile_dir}")
     if losses and main_proc:
         print(f"final loss {losses[-1]:.4f}")
     return TrainRun(engine, state, losses)

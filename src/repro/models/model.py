@@ -197,22 +197,28 @@ def forward_train(
     tokens: jnp.ndarray,
     frontend_embeds: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (logits, aux_loss). tokens: (B,S) or (B,S,K) for audio."""
-    params = cast_params(params, cfg.compute_dtype)
-    x = _embed(params, cfg, tokens)
-    if frontend_embeds is not None:
-        pref = frontend_embeds.astype(cfg.compute_dtype)
-        if "frontend_proj" in params:
-            pref = pref @ params["frontend_proj"]
-        x = jnp.concatenate([pref, x], axis=1)
-    if cfg.learnable_pos_emb:
-        x = x + params["pos_emb"][: x.shape[1]].astype(x.dtype)
+    """Returns (logits, aux_loss). tokens: (B,S) or (B,S,K) for audio.
 
-    x, aux = _run_blocks_train(params, cfg, x)
-    x = apply_norm(params["final_norm"], x)
-    if frontend_embeds is not None:
-        x = x[:, frontend_embeds.shape[1] :]  # logits only over text positions
-    return _logits(params, cfg, x), aux
+    The named scopes `embed`, `blocks` and `head` label the device ops of
+    each part in profiles (PERF.md, Layers)."""
+    params = cast_params(params, cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = _embed(params, cfg, tokens)
+        if frontend_embeds is not None:
+            pref = frontend_embeds.astype(cfg.compute_dtype)
+            if "frontend_proj" in params:
+                pref = pref @ params["frontend_proj"]
+            x = jnp.concatenate([pref, x], axis=1)
+        if cfg.learnable_pos_emb:
+            x = x + params["pos_emb"][: x.shape[1]].astype(x.dtype)
+
+    with jax.named_scope("blocks"):
+        x, aux = _run_blocks_train(params, cfg, x)
+    with jax.named_scope("head"):
+        x = apply_norm(params["final_norm"], x)
+        if frontend_embeds is not None:
+            x = x[:, frontend_embeds.shape[1] :]  # logits only over text positions
+        return _logits(params, cfg, x), aux
 
 
 def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
@@ -234,19 +240,22 @@ def forward_features(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Final-norm hidden states (before the LM head). Returns (x, aux)."""
     params = cast_params(params, cfg.compute_dtype)
-    x = _embed(params, cfg, tokens)
-    if frontend_embeds is not None:
-        pref = frontend_embeds.astype(cfg.compute_dtype)
-        if "frontend_proj" in params:
-            pref = pref @ params["frontend_proj"]
-        x = jnp.concatenate([pref, x], axis=1)
-    if cfg.learnable_pos_emb:
-        x = x + params["pos_emb"][: x.shape[1]].astype(x.dtype)
-    x, aux = _run_blocks_train(params, cfg, x)
-    x = apply_norm(params["final_norm"], x)
-    if frontend_embeds is not None:
-        x = x[:, frontend_embeds.shape[1] :]
-    return x, aux
+    with jax.named_scope("embed"):
+        x = _embed(params, cfg, tokens)
+        if frontend_embeds is not None:
+            pref = frontend_embeds.astype(cfg.compute_dtype)
+            if "frontend_proj" in params:
+                pref = pref @ params["frontend_proj"]
+            x = jnp.concatenate([pref, x], axis=1)
+        if cfg.learnable_pos_emb:
+            x = x + params["pos_emb"][: x.shape[1]].astype(x.dtype)
+    with jax.named_scope("blocks"):
+        x, aux = _run_blocks_train(params, cfg, x)
+    with jax.named_scope("head"):
+        x = apply_norm(params["final_norm"], x)
+        if frontend_embeds is not None:
+            x = x[:, frontend_embeds.shape[1] :]
+        return x, aux
 
 
 def _chunked_ce(params: Dict, cfg: ModelConfig, x: jnp.ndarray, labels: jnp.ndarray):
@@ -292,10 +301,12 @@ def loss_fn(params: Dict, cfg: ModelConfig, batch: Dict) -> Tuple[jnp.ndarray, D
     )
     if use_chunked:
         x, aux = forward_features(params, cfg, tokens, batch.get("frontend"))
-        ce = _chunked_ce(params, cfg, x, labels)
+        with jax.named_scope("head"):
+            ce = _chunked_ce(params, cfg, x, labels)
     else:
         logits, aux = forward_train(params, cfg, tokens, batch.get("frontend"))
-        ce = cross_entropy(logits, labels)
+        with jax.named_scope("head"):
+            ce = cross_entropy(logits, labels)
     return ce + aux, {"ce": ce, "aux": aux}
 
 

@@ -28,9 +28,10 @@ from repro.optim.base import Optimizer
 
 def _push_pop(queue: jnp.ndarray, fresh: jnp.ndarray):
     """queue: (tau, ...). Returns (oldest, new_queue)."""
-    oldest = queue[0]
-    new_q = jnp.concatenate([queue[1:], fresh[None].astype(queue.dtype)], axis=0)
-    return oldest, new_q
+    with jax.named_scope("fifo"):
+        oldest = queue[0]
+        new_q = jnp.concatenate([queue[1:], fresh[None].astype(queue.dtype)], axis=0)
+        return oldest, new_q
 
 
 def delayed_optimizer(
@@ -159,11 +160,12 @@ def stage_delayed_optimizer(
             # pop: stage k reads the entry pushed K-1-k steps ago (row k),
             # restricted to its own stage slice -> queue diagonal; one
             # gather keeps the traced step O(1) in K
-            idx = jnp.arange(K - 1)
-            diag = q[idx, idx]
-            stale = jnp.concatenate([diag, fresh[K - 1 :].astype(q.dtype)], axis=0)
-            new_q = jnp.concatenate([q[1:], fresh[None].astype(q.dtype)], axis=0)
-            return stale, new_q
+            with jax.named_scope("fifo"):
+                idx = jnp.arange(K - 1)
+                diag = q[idx, idx]
+                stale = jnp.concatenate([diag, fresh[K - 1 :].astype(q.dtype)], axis=0)
+                new_q = jnp.concatenate([q[1:], fresh[None].astype(q.dtype)], axis=0)
+                return stale, new_q
         return _push_pop(q, fresh)
 
     def _pop_push_param(q, fresh, s):
@@ -176,10 +178,11 @@ def stage_delayed_optimizer(
         if E == 0:
             return _pop_push(q, fresh, s)
         if s == "stage":
-            idx = jnp.arange(K)
-            stale = q[idx, idx]
-            new_q = jnp.concatenate([q[1:], fresh[None].astype(q.dtype)], axis=0)
-            return stale, new_q
+            with jax.named_scope("fifo"):
+                idx = jnp.arange(K)
+                stale = q[idx, idx]
+                new_q = jnp.concatenate([q[1:], fresh[None].astype(q.dtype)], axis=0)
+                return stale, new_q
         return _push_pop(q, fresh)
 
     def init(params):

@@ -124,14 +124,17 @@ def make_two_version_loss(cfg: ModelConfig) -> Callable:
     block2w.defvjp(block2w_fwd, block2w_bwd)
 
     def loss2w(params_bwd, params_fwd, batch):
-        x = _embed(params_bwd, cfg, batch["tokens"])
-        if cfg.learnable_pos_emb:
-            x = x + params_bwd["pos_emb"][: x.shape[1]].astype(x.dtype)
-        for l in range(cfg.num_layers):
-            x = block2w(params_fwd["blocks"][l], params_bwd["blocks"][l], x, l)
-        x = apply_norm(params_bwd["final_norm"], x)
-        logits = _logits(params_bwd, cfg, x)
-        return cross_entropy(logits, batch["labels"])
+        with jax.named_scope("embed"):
+            x = _embed(params_bwd, cfg, batch["tokens"])
+            if cfg.learnable_pos_emb:
+                x = x + params_bwd["pos_emb"][: x.shape[1]].astype(x.dtype)
+        with jax.named_scope("blocks"):
+            for l in range(cfg.num_layers):
+                x = block2w(params_fwd["blocks"][l], params_bwd["blocks"][l], x, l)
+        with jax.named_scope("head"):
+            x = apply_norm(params_bwd["final_norm"], x)
+            logits = _logits(params_bwd, cfg, x)
+            return cross_entropy(logits, batch["labels"])
 
     return loss2w
 
@@ -167,9 +170,11 @@ def make_sim_train_step(
                 fwd_params, cfg, batch
             )
         if grad_clip:
-            grads = clip_by_global_norm(grads, grad_clip)
-        updates, opt_state = optimizer.update(grads, opt_state, params, step)
-        params = apply_updates(params, updates)
+            with jax.named_scope("clip"):
+                grads = clip_by_global_norm(grads, grad_clip)
+        with jax.named_scope("opt"):
+            updates, opt_state = optimizer.update(grads, opt_state, params, step)
+            params = apply_updates(params, updates)
         return params, opt_state, loss, metrics
 
     # NOTE: no buffer donation — the simulator is CPU-scale and callers often
