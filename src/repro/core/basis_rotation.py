@@ -2,14 +2,26 @@
 
 Per rotatable weight matrix W (m x n):
     G_t  <- grad                                  (original space)
-    M_t  <- b1 M_{t-1} + (1-b1) G_t               (original space, Appendix G)
     if t % freq == 0:  U,V <- Eigenbasis-Estimation(G_t, M_t, U, V)
-    G~ <- U^T G V ; M~ <- U^T M V                 (rotate at use time)
+    G~ <- U^T G V                                 (rotated gradient)
+    M~_t <- b1 M~_{t-1} + (1-b1) G~               (first moment, eigenbasis)
     V~_t <- b2 V~_{t-1} + (1-b2) G~^2             (rotated second moment)
     W <- W - lr * U ( M~ / sqrt(V~ + eps) ) V^T
 
+The paper states M_t in the original space (Appendix G) and rotates it at
+use time. Between refreshes U and V are fixed and the EMA is linear, so
+U^T M_t V = b1 (U^T M_{t-1} V) + (1-b1) U^T G_t V: the state's ``"m"``
+holds M~ = U^T M V (the missing side of a unilateral leaf is the identity),
+and a plain step runs two two-sided products (rotate G, unrotate the step)
+instead of three. A refresh re-expresses M~ in the new basis,
+rotate(unrotate(M~, U, V), U', V') under the ``rebase`` scope inside
+``refresh``: exact, since U and V are square and orthogonal (U U^T = I).
+Source "1st" forms the original-space M_t it needs inside the refresh
+branch. Each matrix of ``"m"`` has the Frobenius norm of the original-space
+M. A checkpoint written while ``"m"`` was original-space is not compatible.
+
 Non-rotatable leaves (embeddings, norms, biases, 1-D params) fall back to
-plain Adam — exactly the paper's setup.
+plain Adam — exactly the paper's setup — with ``"m"`` in the original space.
 
 State is a flat list of per-leaf dicts (ordered like
 ``jax.tree_util.tree_flatten(params)``), which keeps the whole thing a plain
@@ -25,7 +37,7 @@ gives each leaf its own entry, where an entry is either
   * a tuple of K ints — per-stage periods over the leaf's LEADING stage axis
     (the SPMD stage-stacked ``(K, per, m, n)`` layout), refreshed through a
     vectorized per-stage mask: ``refresh_basis`` runs batched over the stage
-    axis and ``jnp.where`` keeps stage k's old basis unless
+    axis and ``jnp.where`` keeps stage k's old basis (and its M~) unless
     ``step % freq[k] == 0``.
 
 A period <= 0 or >= ``stage_aware.NEVER`` means "never refresh" (the basis
@@ -71,22 +83,30 @@ def _init_leaf(p: jnp.ndarray, plan: LeafPlan, source: str) -> dict:
     return st
 
 
-def _refresh_ops(g, m, ops, freq: FreqSpec, step, source: str, beta2: float):
-    """Apply the (possibly per-stage) refresh schedule to (U, V, L, R).
+def _refresh_ops(g, ops, freq: FreqSpec, step, source: str, beta1: float,
+                 beta2: float):
+    """Apply the (possibly per-stage) refresh schedule to (U, V, L, R, M~).
 
-    Scalar periods keep the single ``lax.cond`` on ``step % f == 0`` — the
-    sim backend's bit-for-bit path. Tuple periods vectorize: the refresh runs
-    batched over the leaf's leading stage axis and a per-stage mask selects,
-    per stage, the refreshed or the previous basis (and Fisher EMA — a
-    non-refreshing stage must not advance L/R either, matching the cond).
+    M~ is the previous step's first moment in the basis (U, V); a refresh
+    returns it re-expressed in the new basis. Scalar periods keep the single
+    ``lax.cond`` on ``step % f == 0`` — the sim backend's bit-for-bit path.
+    Tuple periods vectorize: the refresh runs batched over the leaf's leading
+    stage axis and a per-stage mask selects, per stage, the refreshed or the
+    previous basis, Fisher EMA and M~ (a non-refreshing stage must not
+    advance L/R either, matching the cond).
     """
 
     def do_refresh(o):
         # the scope sits inside the branch: ops of the cond itself (operand
         # copies, the identity branch of a plain step) stay outside it
         with jax.named_scope("refresh"):
-            Uo, Vo, Lo, Ro = o
-            return refresh_basis(g, m, Uo, Vo, Lo, Ro, source, beta2)
+            Uo, Vo, Lo, Ro, mo = o
+            with jax.named_scope("rebase"):
+                m_prev = unrotate(mo, Uo, Vo)
+            m = beta1 * m_prev + (1 - beta1) * g if source == "1st" else None
+            U, V, L, R = refresh_basis(g, m, Uo, Vo, Lo, Ro, source, beta2)
+            with jax.named_scope("rebase"):
+                return U, V, L, R, rotate(m_prev, U, V)
 
     if isinstance(freq, tuple):
         K = len(freq)
@@ -158,35 +178,30 @@ def basis_rotation_adam(
         updates = []
         for g, st, plan, f in zip(gflat, state["leaves"], layout, freqs):
             g = g.astype(jnp.float32)
-            m = beta1 * st["m"] + (1 - beta1) * g
             nst = dict(st)
-            nst["m"] = m
 
             if plan.rotate:
-                U, V, L, R = _refresh_ops(
-                    g, m,
-                    (st.get("U"), st.get("V"), st.get("L"), st.get("R")),
-                    f, step, source, beta2,
+                U, V, L, R, m_prev = _refresh_ops(
+                    g,
+                    (st.get("U"), st.get("V"), st.get("L"), st.get("R"), st["m"]),
+                    f, step, source, beta1, beta2,
                 )
+                with jax.named_scope("rotate"):
+                    g_rot = (rotate(g, U, V) if kops is None else
+                             kops.two_sided_rotate(g, U, V, transpose=True))
+                m = beta1 * m_prev + (1 - beta1) * g_rot
                 if kops is not None:
-                    with jax.named_scope("rotate"):
-                        g_rot = kops.two_sided_rotate(g, U, V, transpose=True)
-                        m_rot = kops.two_sided_rotate(m, U, V, transpose=True)
                     step_rot, v = kops.adam_scale(
-                        g_rot, m_rot, st["v"], beta2, eps, bc1, bc2
+                        g_rot, m, st["v"], beta2, eps, bc1, bc2
                     )
                     with jax.named_scope("rotate"):
                         back = kops.two_sided_rotate(step_rot, U, V, transpose=False)
                 else:
-                    with jax.named_scope("rotate"):
-                        g_rot = rotate(g, U, V)
-                        m_rot = rotate(m, U, V)
                     v = beta2 * st["v"] + (1 - beta2) * jnp.square(g_rot)
-                    step_rot = (m_rot / bc1) / (jnp.sqrt(v / bc2) + eps)
+                    step_rot = (m / bc1) / (jnp.sqrt(v / bc2) + eps)
                     with jax.named_scope("rotate"):
                         back = unrotate(step_rot, U, V)
                 upd = -lr * back
-                nst["v"] = v
                 if U is not None:
                     nst["U"] = U
                 if V is not None:
@@ -196,9 +211,11 @@ def basis_rotation_adam(
                 if R is not None:
                     nst["R"] = R
             else:
+                m = beta1 * st["m"] + (1 - beta1) * g
                 v = beta2 * st["v"] + (1 - beta2) * jnp.square(g)
                 upd = -lr * (m / bc1) / (jnp.sqrt(v / bc2) + eps)
-                nst["v"] = v
+            nst["m"] = m
+            nst["v"] = v
 
             updates.append(upd)
             new_leaves.append(nst)

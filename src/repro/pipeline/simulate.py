@@ -49,18 +49,17 @@ def _find_moments(opt_state: Any) -> Optional[Dict]:
 def predict_weights(params, opt_state, delays_tree, lr, eps: float = 1e-8):
     """w_hat = w - lr * tau * m / (sqrt(v) + eps): extrapolate tau steps ahead.
 
-    For basis-rotation state the momentum `m` lives in the ORIGINAL space but
-    the second moment `v` lives in the ROTATED space, so the Adam-style ratio
-    must be formed there: rotate m into the eigenbasis, divide by sqrt(v),
-    and rotate the step back — mirroring the optimizer's own update direction.
-    (Dividing original-space m by rotated-space v elementwise mixes bases and
-    produces an incoherent prediction.)
+    For basis-rotation state both moments of a rotated leaf live in the
+    eigenbasis (`m` holds U^T M V), so the Adam-style ratio is formed there
+    and the step rotated back — mirroring the optimizer's own update
+    direction. (Dividing original-space M by rotated-space v elementwise
+    mixes bases and produces an incoherent prediction.)
     """
     mo = _find_moments(opt_state)
     if mo is None:
         return params
     if "leaves" in mo:  # basis-rotation state: flat leaf list
-        from repro.core.rotation import rotate, unrotate
+        from repro.core.rotation import unrotate
 
         flat, treedef = jax.tree_util.tree_flatten(params)
         dflat, _ = jax.tree_util.tree_flatten(delays_tree)
@@ -70,11 +69,9 @@ def predict_weights(params, opt_state, delays_tree, lr, eps: float = 1e-8):
                 new.append(p)
                 continue
             U, V = st.get("U"), st.get("V")
+            step = st["m"] / (jnp.sqrt(st["v"]) + eps)
             if U is not None or V is not None:
-                m_rot = rotate(st["m"], U, V)
-                step = unrotate(m_rot / (jnp.sqrt(st["v"]) + eps), U, V)
-            else:
-                step = st["m"] / (jnp.sqrt(st["v"]) + eps)
+                step = unrotate(step, U, V)
             new.append((p - lr * d * step).astype(p.dtype))
         return jax.tree_util.tree_unflatten(treedef, new)
     return jax.tree.map(

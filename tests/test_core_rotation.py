@@ -22,6 +22,7 @@ from repro.core.stage_aware import (
     stage_aware_freq,
 )
 from repro.optim import adam, constant_schedule
+from repro.optim.base import bias_correction
 
 
 def test_power_qr_orthonormal_and_converges():
@@ -250,3 +251,154 @@ def test_stage_context_delay_specs_and_scales():
         np.asarray(scales[0]).reshape(-1), np.asarray([2.0, 1.0, 0.0])
     )
     assert scales[1]["e"] == 2 and scales[1]["h"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the first moment kept in the eigenbasis
+# ---------------------------------------------------------------------------
+
+STAGED = (10, 4, NEVER)  # step 4: stage 1 alone; step 10: stage 0 alone
+
+
+def _reference_run(gs, shape, freq, source, geometry, lr=1e-2, b1=0.9,
+                   b2=0.999, eps=1e-8):
+    """The paper's Algorithm 1 as written: M in the original space, the
+    gradient and M rotated and the step unrotated every step (three
+    two-sided products). Yields (update, M, (U, V)) per step."""
+    (plan,) = build_layout({"w": jnp.zeros(shape)}, geometry)
+    batch, (m, n) = shape[:-2], shape[-2:]
+    U = batched_eye(m, batch) if plan.left else None
+    V = batched_eye(n, batch) if plan.right else None
+    L = jnp.zeros(batch + (m, m)) if plan.left and source == "2nd" else None
+    R = jnp.zeros(batch + (n, n)) if plan.right and source == "2nd" else None
+    M, v = jnp.zeros(shape), jnp.zeros(shape)
+    for t, g in enumerate(gs):
+        M = b1 * M + (1 - b1) * g
+        due = np.array([0 < f < NEVER and t % f == 0 for f in
+                        (freq if isinstance(freq, tuple) else (freq,))])
+        if due.any():
+            new = refresh_basis(g, M, U, V, L, R, source, b2)
+            keep = due.reshape((-1,) + (1,) * len(shape[1:]))
+            U, V, L, R = [None if x is None else jnp.where(keep, x, old)
+                          for x, old in zip(new, (U, V, L, R))]
+        bc1, bc2 = bias_correction(b1, jnp.int32(t)), bias_correction(b2, jnp.int32(t))
+        g_rot, m_rot = rotate(g, U, V), rotate(M, U, V)
+        v = b2 * v + (1 - b2) * jnp.square(g_rot)
+        step = (m_rot / bc1) / (jnp.sqrt(v / bc2) + eps)
+        yield -lr * unrotate(step, U, V), M, (U, V)
+
+
+def _gradients(shape, steps, seed=0):
+    """Random gradients around a fixed well-conditioned matrix, so that the
+    power-QR refresh is well posed and two exact implementations agree to
+    rounding."""
+    m, n = shape[-2:]
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    Q1 = jnp.linalg.qr(jax.random.normal(k1, (m, m)))[0]
+    Q2 = jnp.linalg.qr(jax.random.normal(k2, (n, n)))[0]
+    r = min(m, n)
+    C = Q1[:, :r] @ jnp.diag(jnp.linspace(2.0, 1.0, r)) @ Q2[:, :r].T
+    noise = jax.random.normal(k3, (steps,) + shape)
+    return [C + 0.3 * noise[t] for t in range(steps)]
+
+
+def _rel(a, b):
+    return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+
+@pytest.mark.parametrize(
+    "shape,freq,geometry,source,kernels",
+    [
+        ((16, 16), 10, "bilateral", "2nd", False),
+        ((16, 24), 10, "unilateral", "2nd", False),
+        ((16, 16), 10, "bilateral", "1st", False),
+        ((24, 16), 10, "unilateral", "1st", False),
+        ((3, 16, 16), STAGED, "bilateral", "2nd", False),
+        ((3, 16, 24), STAGED, "unilateral", "1st", False),
+        ((16, 16), 10, "bilateral", "2nd", True),
+    ],
+    ids=["scalar-bi-2nd", "scalar-uni-2nd", "scalar-bi-1st", "scalar-uni-1st",
+         "stage-bi-2nd", "stage-uni-1st", "kernels-bi-2nd"],
+)
+def test_eigenbasis_moment_matches_original_space(shape, freq, geometry, source,
+                                                  kernels):
+    """Keeping M~ = U^T M V as the state, re-expressed at each refresh,
+    gives the original-space algorithm's updates over 25 steps (three
+    refreshes), and unrotating the stored m gives back its M.
+
+    Step 0 is Adam's sign step: each rotated entry moves by lr m~/|g~|
+    whatever its size, so an entry of g~ at rounding level takes either
+    sign under either order of the same products. Its update is left out;
+    its moment is compared like every other step's."""
+    steps = 25
+    gs = _gradients(shape, steps)
+    opt = basis_rotation_adam(
+        constant_schedule(1e-2), source=source, geometry=geometry,
+        freq=[freq] if isinstance(freq, tuple) else freq, use_kernels=kernels,
+    )
+    params = {"w": jnp.zeros(shape)}
+    s = opt.init(params)
+    update = jax.jit(lambda g, s, t: opt.update({"w": g}, s, params, t))
+    ref = _reference_run(gs, shape, freq, source, geometry)
+    for t, (g, (want, M, (U, V))) in enumerate(zip(gs, ref)):
+        u, s = update(g, s, jnp.int32(t))
+        st = s["leaves"][0]
+        assert t == 0 or _rel(u["w"], want) < 1e-5, t
+        assert _rel(unrotate(st["m"], st.get("U"), st.get("V")), M) < 1e-5, t
+    B = U if U is not None else V  # the run did refresh: off identity
+    assert float(jnp.max(jnp.abs(B - jnp.eye(B.shape[-1])))) > 1e-3
+
+
+def _dot_generals(jx):
+    """(outside any cond, inside a cond under the `rebase` scope) counts."""
+    from repro.analysis.jaxpr import iter_eqns
+
+    outside = rebase = 0
+    for eq, ctx in iter_eqns(jx):
+        if eq.primitive.name != "dot_general":
+            continue
+        if "cond" not in ctx:
+            outside += 1
+        elif "rebase" in str(eq.source_info.name_stack):
+            rebase += 1
+    return outside, rebase
+
+
+def test_plain_step_runs_two_products_and_refresh_rebases():
+    """One bilateral leaf: four dot_generals outside the refresh cond (rotate
+    the gradient, unrotate the step: two two-sided products, where keeping
+    M in the original space took three), and four in the refresh branch's
+    `rebase` scope (unrotate from the old basis, rotate into the new)."""
+    params = {"w": jnp.zeros((16, 24))}
+    opt = basis_rotation_adam(constant_schedule(1e-2), freq=10)
+    s = opt.init(params)
+    g = {"w": jnp.ones((16, 24))}
+    jx = jax.make_jaxpr(lambda g, s, t: opt.update(g, s, params, t))(
+        g, s, jnp.int32(0))
+    assert _dot_generals(jx) == (4, 4)
+    # unilateral: one side each way
+    uni = basis_rotation_adam(constant_schedule(1e-2), freq=10, geometry="unilateral")
+    su = uni.init(params)
+    jx = jax.make_jaxpr(lambda g, s, t: uni.update(g, s, params, t))(
+        g, su, jnp.int32(0))
+    assert _dot_generals(jx) == (2, 2)
+
+
+def test_stored_moment_keeps_the_per_layer_norm():
+    """Each (stage, layer) matrix of the stored m has the Frobenius norm of
+    the original-space M: what a per-layer gradient-norm reading of
+    m / (1 - b1) relies on."""
+    shape = (2, 3, 16, 24)
+    gs = _gradients(shape, 12, seed=1)
+    opt = basis_rotation_adam(constant_schedule(1e-2), freq=5)
+    params = {"w": jnp.zeros(shape)}
+    s = opt.init(params)
+    update = jax.jit(lambda g, s, t: opt.update({"w": g}, s, params, t))
+    norm = lambda x: jnp.sqrt(jnp.sum(jnp.square(x), axis=(-2, -1)))
+    M = jnp.zeros(shape)
+    for t, g in enumerate(gs):
+        _, s = update(g, s, jnp.int32(t))
+        M = 0.9 * M + 0.1 * g
+        np.testing.assert_allclose(np.asarray(norm(s["leaves"][0]["m"])),
+                                   np.asarray(norm(M)), rtol=1e-5)
+    assert float(jnp.max(jnp.abs(s["leaves"][0]["m"] - M))) > 1e-2  # rotated

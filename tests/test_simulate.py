@@ -49,16 +49,17 @@ def test_predict_weights_moves_against_momentum():
 
 
 def test_predict_weights_rotated_state_coherent():
-    """PipeMare prediction under basis rotation: m must be rotated into the
-    eigenbasis before dividing by the rotated-space v, and the step rotated
-    back — the old elementwise original/rotated mix is a regression."""
+    """PipeMare prediction under basis rotation: the state's m holds the
+    first moment in the eigenbasis (U^T M V), the ratio with the
+    rotated-space v is formed there and the step rotated back — the old
+    elementwise original/rotated mix is a regression."""
     n = 8
     U = jnp.asarray(np.eye(n, dtype=np.float32)[::-1].copy())  # reversal perm
     V = jnp.eye(n, dtype=jnp.float32)
     m = jnp.arange(n * n, dtype=jnp.float32).reshape(n, n) / 10.0
     v = (jnp.arange(n * n, dtype=jnp.float32).reshape(n, n) % 7) + 1.0
     p = jnp.ones((n, n), jnp.float32)
-    state = {"leaves": [{"m": m, "v": v, "U": U, "V": V}]}
+    state = {"leaves": [{"m": U.T @ m @ V, "v": v, "U": U, "V": V}]}
     pred = predict_weights({"w": p}, state, {"w": 3}, lr=0.01)
     m_rot = U.T @ m @ V
     want = p - 0.01 * 3 * (U @ (m_rot / (jnp.sqrt(v) + 1e-8)) @ V.T)

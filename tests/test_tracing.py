@@ -1,9 +1,9 @@
 """Profiler labels of the train step: the named scopes in the compiled
 step's `op_name` metadata (SpmdEngine K=1 fill-drain, K=2 1F1B in a
 subprocess with two host devices, SimEngine), the backward's `transpose(`
-names, the refresh scope confined to the conditional's branch, the
-engine's and loop's host spans in a `jax.profiler` trace, and the names
-of the Pallas kernels."""
+names, the refresh and rebase scopes confined to the conditional's branch,
+the engine's and loop's host spans in a `jax.profiler` trace, and the
+names of the Pallas kernels."""
 import glob
 import math
 import os
@@ -31,7 +31,7 @@ CFG = ModelConfig(
 OCFG = OptimizerConfig(name="basis_rotation", learning_rate=1e-3,
                        total_steps=8, rotation_freq=2)
 MODEL_SCOPES = {"embed", "blocks", "head"}
-OPT_SCOPES = {"clip", "opt", "rotate", "refresh"}
+OPT_SCOPES = {"clip", "opt", "rotate", "refresh", "rebase"}
 ALL_SCOPES = MODEL_SCOPES | OPT_SCOPES | {"exchange", "fifo"}
 
 # ---------------------------------------------------------------------------
@@ -205,6 +205,19 @@ def test_refresh_ops_sit_only_inside_the_conditional_branch(programs, engine):
     assert placed
     assert all(comp in branches for comp, _ in placed), [
         p for p in placed if p[0] not in branches][:5]
+
+
+@pytest.mark.parametrize("engine", sorted(EXPECTED))
+def test_rebase_ops_sit_only_inside_the_refresh_branch(programs, engine):
+    """Re-expressing the first moment in a new basis is refresh-step work:
+    every `rebase` op is under `refresh`, inside the conditional's branch,
+    and the plain step's path holds none."""
+    text = programs[engine]
+    branches = inside_branches(text)
+    placed = [(comp, scopes_of(op)) for comp, instrs in parse_hlo(text).items()
+              for _, op, _, _ in instrs if "rebase" in scopes_of(op)]
+    assert placed
+    assert all(comp in branches and "refresh" in sc for comp, sc in placed)
 
 
 def test_scope_parsing_peels_ad_wrappers_only():
