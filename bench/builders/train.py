@@ -17,24 +17,58 @@ import jax
 import jax.numpy as jnp
 
 
+# keys that describe the configuration and build nothing
+DESCRIPTIVE = ("described_as", "reference", "reduced", "assumed", "deployment", "norm_eps")
+# fields of the program's configuration that the builder and the cell set
+OWNED = ("attention", "moe", "ssm", "pattern", "scan_layers", "use_kernels", "dtype",
+         "param_dtype", "logits_fp32")
+
+
 def model_config(c: Dict[str, Any], precision: str, use_kernels: bool):
-    """The program's ModelConfig for a configuration file."""
+    """The program's ModelConfig for a configuration file, built by field
+    name (published keys renamed by bench.modelkeys). A key that names a
+    field of ModelConfig or AttentionConfig sets it; `attention` sets the
+    attention's `kind`; the keys of the `moe` object set MoEConfig's, and
+    make every layer's MLP an MoE. Any other key, but a descriptive one, is
+    a SpecError that names it: a configuration the program cannot build
+    fails here, never as another model."""
+    from dataclasses import fields
+
+    from bench.modelkeys import canonical
+    from bench.spec import SpecError
     from repro.configs.base import (
-        PRECISION_POLICIES, AttentionConfig, BlockSpec, ModelConfig,
+        PRECISION_POLICIES, AttentionConfig, BlockSpec, ModelConfig, MoEConfig,
     )
 
-    cfg = ModelConfig(
-        name=c["name"], family="dense", source=c["source"],
-        num_layers=c["num_layers"], d_model=c["d_model"], d_ff=c["d_ff"],
-        vocab_size=c["vocab_size"], max_seq_len=c["max_seq_len"],
-        attention=AttentionConfig(num_heads=c["num_heads"],
-                                  num_kv_heads=c["num_kv_heads"],
-                                  head_dim=c["head_dim"],
-                                  rope_theta=c["rope_theta"]),
-        pattern=(BlockSpec("attn", "dense"),), norm=c["norm"],
-        mlp_act=c["mlp_act"], learnable_pos_emb=c["learnable_pos_emb"],
-        tie_embeddings=c["tie_embeddings"], scan_layers=False,
-    )
+    def names(dc):
+        return {f.name for f in fields(dc)}
+
+    c = canonical(c)
+    model, attn = {}, {}
+    for k, v in c.items():
+        if k in DESCRIPTIVE or k == "moe":
+            continue
+        if k == "attention":
+            attn["kind"] = v
+        elif k in OWNED:
+            raise SpecError(f"configuration key {k!r} is set by the builder or the cell")
+        elif k in names(ModelConfig):
+            model[k] = v
+        elif k in names(AttentionConfig) - {"kind"}:
+            attn[k] = v
+        else:
+            raise SpecError(f"configuration key {k!r} names no field of the program's "
+                            "ModelConfig or AttentionConfig")
+    moe = None
+    if "moe" in c:
+        stray = sorted(set(c["moe"]) - names(MoEConfig))
+        if stray:
+            raise SpecError(f"configuration key 'moe.{stray[0]}' names no field of the "
+                            "program's MoEConfig")
+        moe = MoEConfig(**c["moe"])
+    mlp = "dense" if moe is None else "moe"
+    cfg = ModelConfig(**{"family": mlp, **model}, attention=AttentionConfig(**attn),
+                      moe=moe, pattern=(BlockSpec("attn", mlp),), scan_layers=False)
     cfg = PRECISION_POLICIES[precision].apply(cfg)
     return cfg.replace(use_kernels=True) if use_kernels else cfg
 
@@ -128,12 +162,17 @@ def _named(engine, tree, values) -> Dict[str, float]:
 def first_gradient_norms(engine, state, beta1: float = 0.9) -> Dict[str, float]:
     """Norms of the clipped step-0 gradient as the optimizer got it, from
     its state after one step: the newest FIFO entry of a delayed leaf,
-    m / (1 - b1) of a leaf applied at once."""
+    m / (1 - b1) of a leaf applied at once. Basis rotation keeps a list of
+    leaf states; plain Adam a params-shaped tree of `m`."""
 
     treedef = jax.tree_util.tree_structure(state.params)
 
     def grads(opt):
         inner = opt["inner"] if "grad_q" in opt else opt
+        if "leaves" not in inner:
+            if "grad_q" in opt:
+                raise NotImplementedError("first gradient of a delayed plain-Adam leaf")
+            return _norms(engine.name, jax.tree.map(lambda m: m / (1.0 - beta1), inner["m"]))
         queues = opt.get("grad_q") or [None] * len(inner["leaves"])
         flat = [st["m"] / (1.0 - beta1) if q is None else q[-1]
                 for st, q in zip(inner["leaves"], queues)]

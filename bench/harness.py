@@ -21,6 +21,7 @@ import sys
 import tempfile
 import time
 from dataclasses import dataclass, field
+from functools import cached_property
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
@@ -239,6 +240,59 @@ class Context:
     cell: spec.Cell
     peaks: Dict[str, Any]
     flops_per_step: float  # model FLOPs of one step (bench.counts)
+    op_names: Dict[str, str] = field(default_factory=dict)  # the step program's
+    # instruction -> op_name (bench.scopes.op_names); empty without scopes
+    program_spans: List[Any] = field(default_factory=list)  # the program's
+    # `engine.*`/`loop.*` host spans (bench.scopes.program_spans)
+
+    @cached_property
+    def step_seconds(self) -> Optional[List[List[Dict[str, float]]]]:
+        """Per chip, bench.scopes.step_seconds of the traced steps; None
+        where no step program ran."""
+        from bench import scopes
+
+        rows = [scopes.step_seconds(c, len(self.steps)) for c in self.trace.chips]
+        return None if not rows or any(r is None for r in rows) else rows
+
+    @cached_property
+    def device_split(self) -> Optional[Dict[str, float]]:
+        """bench.scopes.device_split of the traced window."""
+        from bench import scopes
+
+        seconds = self.step_seconds
+        return None if seconds is None else scopes.device_split(
+            self.trace.chips, self.steps, self.op_names,
+            self.cell.options.get("rotation_freq"), seconds)
+
+    @cached_property
+    def host_split(self) -> Optional[Dict[str, float]]:
+        """bench.scopes.host_split of the traced window."""
+        from bench import scopes
+
+        return scopes.host_split(self.program_spans, self.trace.window, len(self.steps))
+
+
+def traced_context(p: Prepared, cell: spec.Cell, peaks: Dict[str, Any],
+                   flops_per_step: float, monitor: Optional[CompileMonitor] = None):
+    """The traced window of `trace_periods` refresh periods, reduced:
+    (losses, Context). The op_name map is read once the window has closed
+    (`monitor.in_window` off): it looks up the program the window ran."""
+    from bench import scopes
+    from bench import trace as tr
+
+    o = cell.options
+    log_dir = tempfile.mkdtemp(prefix="bench_trace_")
+    try:
+        losses, steps = traced(p, int(o["trace_periods"]), int(o["rotation_freq"]), log_dir)
+        if monitor is not None:
+            monitor.in_window = False
+        path = tr.find_xplane(log_dir)
+        reduced, spans = tr.load(path), scopes.program_spans(path)
+    finally:
+        shutil.rmtree(log_dir, ignore_errors=True)
+    names = scopes.op_names(p.engine.executable(p.state, p.pool[0], p.step).as_text())
+    return losses, Context(reduced, steps, list(p.timed.dispatch_s), cell, peaks,
+                           flops_per_step, names, spans)
 
 
 def per_layer(cell: spec.Cell, ctx: Context) -> Dict[str, Dict[str, Any]]:
@@ -295,21 +349,15 @@ def run(argv: List[str], t_start: float, require_chip: bool = True,
     monitor.in_window = True
     p.timed.dispatch_s.clear()
     if args.trace:
-        log_dir = tempfile.mkdtemp(prefix="bench_trace_")
-        try:
-            losses, steps = traced(p, int(o["trace_periods"]), period, log_dir)
-            monitor.in_window = False
-            reduced = tr.load(tr.find_xplane(log_dir))
-        finally:
-            shutil.rmtree(log_dir, ignore_errors=True)
+        peaks = counts.chip_peaks(kind) if require_chip else {}
+        losses, ctx = traced_context(p, cell, peaks, flops_per_step, monitor)
+        reduced = ctx.trace
         busy = [tr.total(tr.busy(c)) * 1e-9 for c in reduced.chips]
         device["busy_s"] = sum(busy) / max(len(busy), 1)
         device["window_s"] = reduced.window_s
-        ctx = Context(reduced, steps, list(p.timed.dispatch_s), cell,
-                      counts.chip_peaks(kind) if require_chip else {}, flops_per_step)
         metrics = per_layer(cell, ctx)
         result["breakdown"] = tr.breakdown(reduced)
-        attempted = len(steps)
+        attempted = len(ctx.steps)
     else:
         losses, elapsed, n = measure(p, args.seconds, period)
         monitor.in_window = False

@@ -1,5 +1,6 @@
 """Plain reference of the paper's nanoGPT job: model, loss, gradient clip,
-per-stage gradient delay and Adam with basis rotation, in `jax.numpy`.
+per-stage gradient delay and Adam with basis rotation (or plain Adam, for a
+cell whose `optimizer` is "adam"), in `jax.numpy`.
 
 It imports nothing of the program and takes nothing the program made. It
 writes the architecture from the configuration file and the optimizer from
@@ -42,6 +43,7 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 EXCLUDE = ("embed", "lm_head", "pos_emb", "norm", "bias", "scale")
 ROT_MIN_DIM = 8
+OPTIMIZERS = ("basis_rotation", "adam")
 
 
 # ---------------------------------------------------------------------------
@@ -176,7 +178,8 @@ def clipped_grad(params, c, mode, tokens, labels):
 
 
 # ---------------------------------------------------------------------------
-# optimizer: Adam with basis rotation (2nd-moment source, bilateral)
+# optimizer: Adam with basis rotation (2nd-moment source, bilateral), or
+# plain Adam where no leaf rotates
 # ---------------------------------------------------------------------------
 
 
@@ -190,7 +193,14 @@ def _rotates(path: str, shape) -> bool:
 
 
 def _power_qr(A, Q, prec):
-    Qn, R = jnp.linalg.qr(_hmm("...ij,...jk->...ik", A, Q, prec))
+    """Q' = qr(A Q).Q with diag(R) >= 0. A Q is first divided by its largest
+    entry: the QR's Q does not change under a positive scale, and a small
+    leaf's Fisher factor (entries near 1e-13) would otherwise square to
+    below float32's smallest normal inside the QR, where the TPU flushes to
+    zero and the Q it returns is not orthogonal."""
+    Z = _hmm("...ij,...jk->...ik", A, Q, prec)
+    big = jnp.max(jnp.abs(Z), axis=(-2, -1), keepdims=True)
+    Qn, R = jnp.linalg.qr(Z / jnp.where(big > 0, big, 1.0))
     sign = jnp.sign(jnp.diagonal(R, axis1=-2, axis2=-1))
     return Qn * jnp.where(sign == 0, 1.0, sign)[..., None, :]
 
@@ -199,10 +209,12 @@ def _eye(n, batch):
     return jnp.broadcast_to(jnp.eye(n, dtype=jnp.float32), batch + (n, n))
 
 
-def opt_init(params):
+def opt_init(params, rotation: bool = True):
+    """Adam's moments for every leaf; with `rotation`, the bases and Fisher
+    factors of the leaves that rotate. Without it, plain Adam."""
     def leaf(path, p):
         st = {"m": jnp.zeros_like(p), "v": jnp.zeros_like(p)}
-        if _rotates(path, p.shape):
+        if rotation and _rotates(path, p.shape):
             b, (m, n) = p.shape[:-2], p.shape[-2:]
             st.update(U=_eye(m, b), V=_eye(n, b), L=jnp.zeros(b + (m, m)),
                       R=jnp.zeros(b + (n, n)))
@@ -320,7 +332,9 @@ def readings(key, c: Dict[str, Any], opts: Dict[str, Any], batches, mode: str = 
     c = _Hashable(c)
     params = flat(jax.jit(init_params, static_argnums=1)(key, c))
     p0 = dict(params)
-    state = opt_init(unflat(params))
+    if opts["optimizer"] not in OPTIMIZERS:
+        raise ValueError(f"the reference has no optimizer {opts['optimizer']!r}")
+    state = opt_init(unflat(params), opts["optimizer"] == "basis_rotation")
     hist: Dict[int, Dict[str, Any]] = {}
     losses, grad0 = [], None
     for t in range(steps):

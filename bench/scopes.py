@@ -2,7 +2,7 @@
 
 Device side: the step program names its parts with `jax.named_scope`
 (`embed`, `blocks`, `head`, `exchange`, `clip`, `opt`, `rotate`, `refresh`,
-`fifo`). The scopes reach the compiled program's HLO as
+`rebase`, `fifo`). The scopes reach the compiled program's HLO as
 `metadata={op_name=...}`; a trace names each device op by its HLO text,
 which starts with the instruction's `%name`. `op_names` joins the two.
 
@@ -21,25 +21,30 @@ is only what its body ops leave uncovered. An op is
   `jvp(blocks)` and the others; recomputed forward ops inside it too);
 * else forward when it is under `embed`, `blocks`, `head` or `exchange`;
 * else optimizer when it is under `clip` or `opt` (`rotate`, `refresh`
-  and `fifo` sit inside `opt`);
+  and `fifo` sit inside `opt`, `rebase` inside `refresh`);
 * else unscoped.
+
+The harness reads the op_name map and the host spans after its traced
+window into its Context (`op_names`, `program_spans`); per-layer metrics
+read them through `device_split`, `host_split` and `seconds_under`.
 """
 from __future__ import annotations
 
 import re
 from collections import defaultdict
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
-from bench.trace import Chip, Op
+from bench.trace import Chip, Op, innermost_seconds
 
 FORWARD = ("embed", "blocks", "head", "exchange")
-OPTIMIZER = ("clip", "opt", "rotate", "refresh", "fifo")
+OPTIMIZER = ("clip", "opt", "rotate", "refresh", "rebase", "fifo")
 SCOPES = FORWARD + OPTIMIZER
 PROGRAM_SPAN_PREFIXES = ("engine.", "loop.")
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _WRAPPED = re.compile(r"(\w+)\((.*)\)")
+_SUFFIX = re.compile(r"(\.\d+)+$")
 
 
 def instruction(label: str) -> str:
@@ -59,8 +64,8 @@ def op_names(hlo_text: str) -> Dict[str, str]:
     return out
 
 
-def scopes_of(op_name: str) -> set:
-    """The scopes on an op_name path. AD wrappers (`jvp(...)`,
+def segments(op_name: str) -> set:
+    """The segments of an op_name path. AD wrappers (`jvp(...)`,
     `transpose(...)`) are peeled off a segment; `jit(...)` is a function
     name, not a scope, and stays."""
     out = set()
@@ -70,7 +75,21 @@ def scopes_of(op_name: str) -> set:
             seg = m.group(2)
             m = _WRAPPED.fullmatch(seg)
         out.add(seg)
-    return out & set(SCOPES)
+    return out
+
+
+def scopes_of(op_name: str) -> set:
+    """The scopes (SCOPES) on an op_name path."""
+    return segments(op_name) & set(SCOPES)
+
+
+def custom_call(label: str) -> Optional[str]:
+    """The name of a custom call from its HLO text, numeric suffixes off
+    (`%flash_fwd.3 = ... custom-call(...)` -> `flash_fwd`: a Pallas kernel's
+    `name`); None for any other op."""
+    if "custom-call(" not in label:
+        return None
+    return _SUFFIX.sub("", instruction(label))
 
 
 def category(op_name: str) -> str:
@@ -85,37 +104,6 @@ def category(op_name: str) -> str:
     return "unscoped"
 
 
-def innermost_seconds(ops: Iterable[Op], start: float, end: float) -> Dict[str, float]:
-    """Seconds of [start, end) per op label, each instant to the innermost op
-    covering it. Ops nest or follow each other; one that overlaps its
-    enclosing op's end is cut there."""
-    acc: Dict[str, float] = defaultdict(float)
-    stack: List[Tuple[float, str]] = []  # (end, label), innermost last
-    t = start
-
-    def close_until(limit: float) -> None:
-        nonlocal t
-        while stack and stack[-1][0] <= limit:
-            e, label = stack.pop()
-            if e > t:
-                acc[label] += e - t
-                t = e
-
-    live = sorted((o for o in ops if o.end > start and o.start < end),
-                  key=lambda o: (o.start, -o.end))
-    for o in live:
-        s, e = max(o.start, start), min(o.end, end)
-        close_until(s)
-        if stack:
-            if s > t:
-                acc[stack[-1][1]] += s - t
-            e = min(e, stack[-1][0])
-        t = max(t, s)
-        stack.append((e, o.label))
-    close_until(float("inf"))
-    return {k: v * 1e-9 for k, v in acc.items()}
-
-
 def step_executions(chip: Chip, n: int) -> Optional[List[Op]]:
     """The step program's executions on a chip, in order: the program that
     runs once per traced step, the longest such (bench.stepprog's rule)."""
@@ -128,20 +116,36 @@ def step_executions(chip: Chip, n: int) -> Optional[List[Op]]:
     return max(steps, key=lambda r: sum(m.dur for m in r))
 
 
-def per_step(chip: Chip, n: int, names: Dict[str, str]) -> Optional[List[Dict[str, float]]]:
-    """For each traced step on a chip: device seconds per category, per
-    scope (`rotate`, `refresh`, `fifo`, ...) and `busy`."""
+def step_seconds(chip: Chip, n: int) -> Optional[List[Dict[str, float]]]:
+    """For each of the n traced steps on a chip: the step program's device
+    seconds per op label, each instant to the innermost op. None where no
+    step program ran."""
     runs = step_executions(chip, n)
     if runs is None:
         return None
+    return [innermost_seconds(chip.ops, m.start, m.end) for m in runs]
+
+
+def is_refresh(t: int, freq: Optional[int]) -> bool:
+    """Step t refreshes the bases (t % freq == 0); no step does without a
+    refresh period."""
+    return bool(freq) and t % freq == 0
+
+
+def per_step(seconds: List[Dict[str, float]], names: Dict[str, str]) -> List[Dict[str, float]]:
+    """For each step's `step_seconds`: device seconds per category, per
+    scope (`rotate`, `refresh`, `fifo`, ...) and `busy`."""
+    kinds: Dict[str, Tuple[str, set]] = {}
     out = []
-    for m in runs:
-        secs = innermost_seconds(chip.ops, m.start, m.end)
+    for secs in seconds:
         row: Dict[str, float] = defaultdict(float)
         for label, s in secs.items():
-            op = names.get(instruction(label), "")
-            row[category(op)] += s
-            for scope in scopes_of(op):
+            if label not in kinds:
+                op = names.get(instruction(label), "")
+                kinds[label] = (category(op), scopes_of(op))
+            cat, scopes = kinds[label]
+            row[cat] += s
+            for scope in scopes:
                 row[scope] += s
             row["busy"] += s
         out.append(dict(row))
@@ -149,21 +153,24 @@ def per_step(chip: Chip, n: int, names: Dict[str, str]) -> Optional[List[Dict[st
 
 
 def device_split(chips: Sequence[Chip], steps: Sequence[int], names: Dict[str, str],
-                 freq: int) -> Optional[Dict[str, float]]:
+                 freq: Optional[int], seconds=None) -> Optional[Dict[str, float]]:
     """fwd/bwd ms per step, opt/rotate ms per plain step (t % freq != 0),
-    refresh ms per refresh step, the unscoped share of the busy time in %,
-    and each category's and the busy ms of a plain step; averaged over
-    chips. None where the program carries no scopes (a build without them)
-    or no step program ran."""
+    refresh and rebase ms per refresh step, the unscoped share of the busy
+    time in %, and each category's and the busy ms of a plain step;
+    averaged over chips. `seconds`, where given, is each chip's
+    `step_seconds`. None where the program carries no scopes (a build
+    without them) or no step program ran."""
     if not any(scopes_of(op) for op in names.values()):
         return None
+    if seconds is None:
+        seconds = [step_seconds(c, len(steps)) for c in chips]
     per_chip = []
-    for chip in chips:
-        rows = per_step(chip, len(steps), names)
-        if rows is None:
+    for secs in seconds:
+        if secs is None:
             return None
-        plain = [r for t, r in zip(steps, rows) if t % freq != 0]
-        fresh = [r for t, r in zip(steps, rows) if t % freq == 0]
+        rows = per_step(secs, names)
+        plain = [r for t, r in zip(steps, rows) if not is_refresh(t, freq)]
+        fresh = [r for t, r in zip(steps, rows) if is_refresh(t, freq)]
 
         def mean_ms(rs, key):
             return 1e3 * sum(r.get(key, 0.0) for r in rs) / len(rs) if rs else None
@@ -175,6 +182,7 @@ def device_split(chips: Sequence[Chip], steps: Sequence[int], names: Dict[str, s
             "opt_ms": mean_ms(plain, "optimizer"),
             "rotate_ms": mean_ms(plain, "rotate"),
             "refresh_device_ms": mean_ms(fresh, "refresh"),
+            "rebase_ms": mean_ms(fresh, "rebase"),
             "unscoped_share": (100.0 * sum(r.get("unscoped", 0.0) for r in rows) / busy
                                if busy else None),
             **{f"plain_{k}_ms": mean_ms(plain, k)
@@ -183,6 +191,34 @@ def device_split(chips: Sequence[Chip], steps: Sequence[int], names: Dict[str, s
     return {k: (None if any(c[k] is None for c in per_chip)
                 else sum(c[k] for c in per_chip) / len(per_chip))
             for k in per_chip[0]} if per_chip else None
+
+
+WHICH = ("all", "plain", "refresh")
+
+
+def seconds_under(ctx, segment: str, which: str = "all") -> Optional[float]:
+    """Device ms per step of the ops that have an op_name path segment
+    `segment` (AD wrappers peeled; listed in SCOPES or not) or are a custom
+    call of that name, averaged over the traced steps that `which` names
+    (all, plain or refresh steps) and over chips. Reads a harness Context.
+    None where no op is so named or no such step was traced."""
+    if which not in WHICH:
+        raise ValueError(f"which must be one of {WHICH}, not {which!r}")
+    seconds, names = ctx.step_seconds, ctx.op_names
+    freq = ctx.cell.options.get("rotation_freq")
+    keep = [i for i, t in enumerate(ctx.steps)
+            if which == "all" or is_refresh(t, freq) == (which == "refresh")]
+    if seconds is None or not keep:
+        return None
+    labels = {label for rows in seconds for row in rows for label in row}
+    hits = {label for label in labels
+            if segment in segments(names.get(instruction(label), ""))
+            or custom_call(label) == segment}
+    if not hits:
+        return None
+    per_chip = [1e3 * sum(s for i in keep for label, s in rows[i].items() if label in hits)
+                / len(keep) for rows in seconds]
+    return sum(per_chip) / len(per_chip)
 
 
 def host_split(spans: Sequence[Op], window: Tuple[float, float],

@@ -5,17 +5,14 @@
 
 Runs the cell as `bench/run.py` does (the harness's set-up, an untraced
 window of `--seconds`, then the traced window of `trace_periods` refresh
-periods) and keeps what the result line of `bench/run.py --trace 1` cannot
-hold yet: the compiled step's `op_name` map, read through
-`engine.executable(...)`, and the program's `engine.*`/`loop.*` host spans
-(bench.scopes). Prints one JSON object:
+periods, read into the harness's Context) and prints, by hand, more than a
+result line holds. One JSON object:
 
-* `device`: fwd/bwd/opt/rotate/refresh device ms and the unscoped share
-  (`bench.scopes.device_split`), and the top ops of each category;
-* `host`: ms per step in `engine.args` + `engine.lookup`, in
-  `engine.launch`, and `engine.compile` spans per step
-  (`bench.scopes.host_split`);
-* `readers`: the cell's accepted per-layer metrics on the same trace;
+* `device`: `bench.scopes.device_split`, and the top ops of each category;
+* `scopes`: for each of the program's scopes, `bench.scopes.seconds_under`
+  over all, plain and refresh steps;
+* `host`: `bench.scopes.host_split`;
+* `readers`: the cell's per-layer metrics on the same trace;
 * `untraced`: the same harness numbers from the untraced window;
 * `clock`: whether each step program execution starts after the start of
   the `engine.launch` span that launched it.
@@ -28,9 +25,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
-import shutil  # noqa: E402
 import sys  # noqa: E402
-import tempfile  # noqa: E402
 from collections import defaultdict  # noqa: E402
 from pathlib import Path  # noqa: E402
 
@@ -40,19 +35,19 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 TOP = 8
 
 
-def top_ops(chips, steps, names, n=TOP):
+def top_ops(ctx, n=TOP):
     """Per category, the ops with the most innermost device time, in ms per
     step, averaged over chips."""
     from bench import scopes
 
     acc = defaultdict(lambda: defaultdict(float))
-    for chip in chips:
-        for m in scopes.step_executions(chip, len(steps)) or []:
-            for label, s in scopes.innermost_seconds(chip.ops, m.start, m.end).items():
+    for rows in ctx.step_seconds or []:
+        for secs in rows:
+            for label, s in secs.items():
                 inst = scopes.instruction(label)
-                op = names.get(inst, "")
+                op = ctx.op_names.get(inst, "")
                 acc[scopes.category(op)][f"{inst} {op}"] += s
-    scale = 1e3 / (len(steps) * max(len(chips), 1))
+    scale = 1e3 / (len(ctx.steps) * max(len(ctx.trace.chips), 1))
     return {cat: [[k, v * scale] for k, v in sorted(ops.items(), key=lambda kv: -kv[1])[:n]]
             for cat, ops in acc.items()}
 
@@ -70,7 +65,6 @@ def launch_leads(spans, chip, n):
 
 def main() -> int:
     from bench import counts, harness, scopes, spec
-    from bench import trace as tr
 
     ap = argparse.ArgumentParser(prog="bench/split.py")
     ap.add_argument("--workload", required=True)
@@ -101,35 +95,22 @@ def main() -> int:
                 "steps": n}
 
     p.timed.dispatch_s.clear()
-    log_dir = tempfile.mkdtemp(prefix="bench_split_")
-    try:
-        t0 = time.perf_counter()
-        _, steps = harness.traced(p, int(o["trace_periods"]), period, log_dir)
-        traced_wall = time.perf_counter() - t0
-        path = tr.find_xplane(log_dir)
-        reduced = tr.load(path)
-        spans = scopes.program_spans(path)
-    finally:
-        shutil.rmtree(log_dir, ignore_errors=True)
-    text = p.engine.executable(p.state, p.pool[0], p.step).as_text()
-    names = scopes.op_names(text)
-
-    ctx = harness.Context(reduced, steps, list(p.timed.dispatch_s), cell, peaks, flops)
-    readers = {m["name"]: spec.metric_reader(m["name"])(ctx) for m in cell.per_layer}
-    leads = [x for c in reduced.chips for x in launch_leads(spans, c, len(steps))]
+    _, ctx = harness.traced_context(p, cell, peaks, flops)
+    steps, spans = ctx.steps, ctx.program_spans
+    leads = [x for c in ctx.trace.chips for x in launch_leads(spans, c, len(steps))]
     result = {
         "workload": cell.name, "seed": args.seed, "setup_s": setup_s,
-        "device": {**(scopes.device_split(reduced.chips, steps, names, period) or {}),
-                   "top_ops": top_ops(reduced.chips, steps, names)},
-        "host": scopes.host_split(spans, reduced.window, len(steps)),
-        "readers": readers,
-        "traced": {"step_wall_ms": 1e3 * traced_wall / len(steps), "steps": len(steps)},
+        "device": {**(ctx.device_split or {}), "top_ops": top_ops(ctx)},
+        "scopes": {s: {w: scopes.seconds_under(ctx, s, w) for w in scopes.WHICH}
+                   for s in scopes.SCOPES},
+        "host": ctx.host_split,
+        "readers": {m["name"]: spec.metric_reader(m["name"])(ctx) for m in cell.per_layer},
         "untraced": untraced,
         "clock": {"steps": len(steps),
                   "launch_spans": sum(s.name == "engine.launch" for s in spans),
                   "min_lead_us": min(leads) * 1e-3 if leads else None,
                   "all_after_launch": bool(leads) and min(leads) >= 0},
-        "hlo_instructions_named": len(names),
+        "hlo_instructions_named": len(ctx.op_names),
     }
     line = json.dumps(result)
     if args.out:

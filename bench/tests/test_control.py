@@ -6,13 +6,16 @@ the CPU, where the program's f32 is exact; the chip cells' limits are in
 their cell files, with their readings in PERF.md."""
 from pathlib import Path
 
+import pytest
+
 from bench import control, spec
 
 DATA = Path(__file__).resolve().parent / "data"
 
 
-def test_control_and_half_batch_fail_where_the_program_passes():
-    cell = spec.load_cell("tiny.sim4", DATA, DATA / "BENCHMARK.json")
+@pytest.mark.parametrize("workload", ["tiny.sim4", "tiny.spmd1.adam"])
+def test_control_and_half_batch_fail_where_the_program_passes(workload):
+    cell = spec.load_cell(workload, DATA, DATA / "BENCHMARK.json")
     limits = cell.options["limits"]
     r = control.seed_readings(cell, 12345)
 

@@ -92,3 +92,22 @@ def test_pipeline_without_its_exchange_is_caught(fault, correct):
     assert out.returncode == 0, out.stderr[-3000:]
     r = json.loads(out.stdout.strip().splitlines()[-1])
     assert r["correct"] is correct, r["checks"]
+
+
+def run_adam(wrap=None):
+    return harness.run(["--workload", "tiny.spmd1.adam", "--seed", "3000000019",
+                        "--seconds", "0.5"], time.perf_counter(), require_chip=False,
+                       bench_dir=DATA, benchmark=DATA / "BENCHMARK.json", wrap_step=wrap)
+
+
+@pytest.mark.parametrize("fault, reading", [(None, None), (Unchanged, "change_gap"),
+                                            (HalfBatch, "grad_gap")])
+def test_plain_adam_cell(fault, reading):
+    """The plain-Adam spmd cell against the reference's plain Adam: a sound
+    run is correct, and each fault is caught."""
+    r = run_adam(fault)
+    if fault is None:
+        assert r["correct"], r["checks"]
+    else:
+        assert not r["correct"]
+        assert r["checks"][reading]["value"] > r["checks"][reading]["limit"]

@@ -105,12 +105,45 @@ def span_at(spans: List[Op], t: float) -> str:
     return best.name if best is not None else "none"
 
 
+def innermost_seconds(ops: Iterable[Op], start: float, end: float) -> Dict[str, float]:
+    """Seconds of [start, end) per op label, each instant to the innermost op
+    covering it. Ops nest or follow each other; one that overlaps its
+    enclosing op's end is cut there."""
+    acc: Dict[str, float] = defaultdict(float)
+    stack: List[Tuple[float, str]] = []  # (end, label), innermost last
+    t = start
+
+    def close_until(limit: float) -> None:
+        nonlocal t
+        while stack and stack[-1][0] <= limit:
+            e, label = stack.pop()
+            if e > t:
+                acc[label] += e - t
+                t = e
+
+    live = sorted((o for o in ops if o.end > start and o.start < end),
+                  key=lambda o: (o.start, -o.end))
+    for o in live:
+        s, e = max(o.start, start), min(o.end, end)
+        close_until(s)
+        if stack:
+            if s > t:
+                acc[stack[-1][1]] += s - t
+            e = min(e, stack[-1][0])
+        t = max(t, s)
+        stack.append((e, o.label))
+    close_until(float("inf"))
+    return {k: v * 1e-9 for k, v in acc.items()}
+
+
 def op_seconds(r: Reduced) -> Dict[str, float]:
-    """Device seconds per operation name, averaged over chips."""
+    """Device seconds per operation name, averaged over chips: each instant
+    of the window to the innermost op running then, so that a `while` or
+    `conditional` counts only what its body's ops leave uncovered."""
     acc: Dict[str, float] = defaultdict(float)
     for c in r.chips:
-        for o in c.ops:
-            acc[o.name] += o.dur * 1e-9
+        for name, s in innermost_seconds(c.ops, *r.window).items():
+            acc[name] += s
     return {k: v / max(len(r.chips), 1) for k, v in acc.items()}
 
 
